@@ -90,6 +90,8 @@ def main() -> None:
                     help="also write executed suites' rows to this JSON file")
     args = ap.parse_args()
 
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     suites = get_suites()
     only = None
     if args.only:

@@ -59,11 +59,11 @@ def _lane_stats(results):
 
 def run(n=12, gen_tokens=8, stream_batches=3):
     from repro.core.types import Message, Request
-    from repro.launch.serve import build_router
+    from repro.launch.serve import build_dsl, build_router
 
     router, fleet = build_router(
-        reduced=True, gen_tokens=gen_tokens,
-        lanes=("text", "image", "audio"))
+        build_dsl(("text", "image", "audio")), reduced=True,
+        gen_tokens=gen_tokens)
     reqs = [Request(messages=[Message("user", p)], user=f"user{i % 3}")
             for i, p in enumerate(_mixed(n))]
 

@@ -14,7 +14,6 @@ from typing import Any
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 BLOCK = 256
@@ -61,8 +60,8 @@ def compressed_psum_mean(grads: Any, mesh: Mesh, axis: str = "data",
             return _dequantize(qs, ss, pad, gl.shape, gl.dtype) / n
 
         spec = P(*([None] * g.ndim))
-        return shard_map(body, mesh=mesh, in_specs=(spec,), out_specs=spec,
-                         check_rep=False)(g)
+        return jax.shard_map(body, mesh=mesh, in_specs=(spec,),
+                             out_specs=spec, check_vma=False)(g)
 
     leaves, treedef = jax.tree_util.tree_flatten(grads)
     out = [reduce_leaf(i, g) for i, g in enumerate(leaves)]

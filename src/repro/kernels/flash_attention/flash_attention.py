@@ -26,7 +26,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.pallas_compat import CompilerParams
+from repro.kernels.platform import pallas_call
 
 NEG_INF = -1e30
 
@@ -101,7 +101,7 @@ def _kernel(lens_ref,                      # scalar prefetch: (B,) int32
 
 def flash_attention_folded(q, k, v, lens, *, causal: bool, window: int,
                            q_offset: int, block_q: int = 128,
-                           block_k: int = 128, interpret: bool = True):
+                           block_k: int = 128):
     """q: (BH, Sq, hd); k/v: (BHkv, Skv, hd); lens: (B,) int32 or None."""
     BH, Sq, hd = q.shape
     BHkv, Skv, _ = k.shape
@@ -141,11 +141,10 @@ def flash_attention_folded(q, k, v, lens, *, causal: bool, window: int,
             pltpu.VMEM((block_q, hd), jnp.float32),
         ],
     )
-    return pl.pallas_call(
+    return pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=interpret,
     )(lens, q, k, v)

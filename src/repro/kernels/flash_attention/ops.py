@@ -1,8 +1,8 @@
 """jit'd public wrapper for the flash-attention kernel.
 
-Handles (B, S, H, hd) layout folding, GQA head mapping, dtype dispatch and
-the interpret-mode switch (CPU container validates the kernel body in
-interpret mode; on TPU pass ``interpret=False``).
+Handles (B, S, H, hd) layout folding, GQA head mapping and dtype dispatch.
+The kernel is compiled when lowered for a TPU and interpreted when lowered
+for the CPU (``repro.kernels.platform``).
 """
 
 from __future__ import annotations
@@ -18,12 +18,11 @@ from repro.kernels.flash_attention.flash_attention import \
 
 
 @functools.partial(jax.jit, static_argnames=(
-    "causal", "sliding_window", "q_offset", "block_q", "block_k",
-    "interpret"))
+    "causal", "sliding_window", "q_offset", "block_q", "block_k"))
 def flash_attention(q, k, v, lens: Optional[jax.Array] = None, *,
                     causal: bool = False, sliding_window: int = 0,
                     q_offset: int = 0, block_q: int = 128,
-                    block_k: int = 128, interpret: bool = True):
+                    block_k: int = 128):
     """q: (B, Sq, Hq, hd); k/v: (B, Skv, Hkv, hd); lens: (B,) valid KV len.
 
     Returns (B, Sq, Hq, hd) in q.dtype."""
@@ -35,6 +34,5 @@ def flash_attention(q, k, v, lens: Optional[jax.Array] = None, *,
     lens_i = None if lens is None else lens.astype(jnp.int32)
     out = flash_attention_folded(
         qf, kf, vf, lens_i, causal=causal, window=sliding_window,
-        q_offset=q_offset, block_q=block_q, block_k=block_k,
-        interpret=interpret)
+        q_offset=q_offset, block_q=block_q, block_k=block_k)
     return out.reshape(B, Hq, Sq, hd).transpose(0, 2, 1, 3)

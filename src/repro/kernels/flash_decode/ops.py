@@ -1,37 +1,45 @@
-"""Single-token GQA decode attention (KV-cache scan), Pallas-backed.
+"""Decode attention over a KV cache, Pallas-backed.
 
-Decode is the memory-bound serving hot path (decode_32k / long_500k cells):
-per step each KV block is streamed HBM->VMEM exactly once with online
-softmax.  The tile math is shared with ``kernels/flash_attention`` — decode
-is the Sq=G specialization of the folded kernel: the G grouped q-heads of one
-KV head become the q-tile rows, so the MXU tile is (G, hd) x (hd, bk).
-Rows are padded to the 8-sublane minimum for TPU tiling.
+``flash_decode`` is the contiguous-cache GQA decode: the Sq=G
+specialization of the folded flash-attention kernel (the G grouped q-heads
+of one KV head become the q-tile rows, padded to the 8-sublane minimum).
 
-Paged variants (``paged_flash_decode`` / ``paged_flash_decode_mla``) read
-the physical block pool DIRECTLY through each row's block table: the table
-and per-row ``kv_len`` ride the scalar-prefetch channel
-(``pltpu.PrefetchScalarGridSpec``), so the KV BlockSpec index map resolves
-``tbl[row, ki]`` on the scalar core one grid step ahead of the compute —
-only the row's LIVE physical blocks are ever DMA'd HBM->VMEM.  Nothing
-materializes the ``(B, max_blocks*block_tokens, ...)`` gathered view the
-old fallback built (``gather_kv`` below survives purely as the test
-oracle's gather helper).  Grid iterations past a row's last live block
-clamp their index map to the last live block — Pallas skips the copy for
-a repeated block index — and skip their compute via ``pl.when``; a row
-with ``kv_len == 0`` contributes exact zeros.
+The paged kernels read the physical block pool DIRECTLY through each
+row's block table: the table and per-row ``kv_len`` ride the
+scalar-prefetch channel (``pltpu.PrefetchScalarGridSpec``), so the KV
+BlockSpec index map resolves ``tbl[row, ki]`` on the scalar core one grid
+step ahead of the compute — only the row's LIVE physical blocks are ever
+DMA'd HBM->VMEM, and nothing materializes a ``(B, max_blocks *
+block_tokens, ...)`` gathered view.  Grid steps past a row's last live
+block clamp their index map to that block — Pallas skips the copy for a
+repeated block index — and skip their compute via ``pl.when``; a row with
+``kv_len == 0`` contributes exact zeros.  There is one kernel per
+attention kind, written for W query positions per row (speculative
+verify); a decode step calls it with W=1:
 
-Numerics: decode must be TOKEN-EXACT against the XLA decode path
-(``layers.sdpa`` / ``layers.mla_attention``) — greedy sampling flips on
-last-ulp logit ties, so "close" is not enough.  The paged kernels
-therefore stash per-block scores and values in VMEM scratch while
-streaming, and run ONE full softmax + PV contraction at the final grid
-step with the exact op order of the XLA path — including the cast of the
-probabilities to the value dtype before the PV product (the XLA paths
-quantize there; an online-softmax f32 accumulation diverges by ~4e-3 on
-bf16 serving configs, enough to flip argmax).  The scratch is
-O(max_blocks * block_tokens) per (row, head) program — decode contexts
-at serving scale are VMEM-resident; a truly long-context deployment
-would trade this bit-exactness back for streaming online softmax.
+* GQA (``paged_flash_verify``): the pool is head-major, ``(num_blocks,
+  Hkv, block_tokens, hd)``, so one grid step DMAs a whole page — every KV
+  head — as ``(Hkv, blk, hd)`` tiles whose last two dims are the pool's
+  own (Mosaic's block-shape rule).
+* MLA (``paged_flash_verify_mla``): the absorbed-latent cache
+  ``(num_blocks, block_tokens, r)`` is one latent "head" shared by every
+  query head.
+
+Numerics: on CPU the paged kernels are TOKEN-EXACT against the XLA decode
+path (``layers.sdpa`` / ``layers.mla_attention``) — greedy sampling flips
+on last-ulp logit ties, so "close" is not enough there.  They therefore
+stash per-block scores and values in VMEM scratch while streaming, and
+run ONE full softmax + PV contraction at the final grid step with the op
+order of the XLA path, including the cast of the probabilities to the
+value dtype before the PV product (an online-softmax f32 accumulation
+diverges by ~4e-3 on bf16 configs, enough to flip argmax).  Scores are
+stashed token-major, ``(S, rows)``, so each block lands at a sublane
+offset that is a multiple of the block size (a lane-major stash at
+offset ``ki * blk`` is refused by Mosaic: lane offsets must be multiples
+of 128); the final step transposes the stash back to ``(rows, S)``.  The
+scratch is O(max_blocks * block_tokens) per program — serving contexts
+are VMEM-resident; a long-context deployment would trade this
+bit-exactness for a streaming online softmax.
 """
 
 from __future__ import annotations
@@ -46,14 +54,18 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.flash_attention.flash_attention import \
     flash_attention_folded
-from repro.kernels.pallas_compat import CompilerParams
+from repro.kernels.platform import pallas_call
 
 NEG_INF = -1e30
+_LANES = 128
+# Mosaic's default scoped-VMEM budget; a kernel asks for its scratch and
+# double-buffered blocks on top of it (capped below v5e's 128 MiB VMEM)
+_VMEM_DEFAULT = 16 << 20
+_VMEM_CAP = 100 << 20
 
 
-@functools.partial(jax.jit, static_argnames=("block_k", "interpret"))
-def flash_decode(q, k, v, kv_len, *, block_k: int = 128,
-                 interpret: bool = True):
+@functools.partial(jax.jit, static_argnames=("block_k",))
+def flash_decode(q, k, v, kv_len, *, block_k: int = 128):
     """q: (B, Hq, hd); k/v: (B, Skv, Hkv, hd); kv_len: (B,) int32.
 
     Returns (B, Hq, hd)."""
@@ -70,72 +82,24 @@ def flash_decode(q, k, v, kv_len, *, block_k: int = 128,
 
     out = flash_attention_folded(
         qf, kf, vf, kv_len.astype(jnp.int32), causal=False, window=0,
-        q_offset=0, block_q=Gp, block_k=block_k, interpret=interpret)
+        q_offset=0, block_q=Gp, block_k=block_k)
     out = out[:, :G, :].reshape(B, Hkv, G, hd).reshape(B, Hq, hd)
     return out
 
 
-def gather_kv(pool, tbl):
-    """Materialize per-row contiguous KV views from a paged pool.
-
-    pool: (num_blocks, block_tokens, Hkv, hd) physical blocks;
-    tbl: (B, max_blocks) int32 block table (0 = trash block).
-    Returns (B, max_blocks * block_tokens, Hkv, hd).  This is the TEST
-    oracle's gather — the serving kernels below never build this tensor;
-    they stream blocks through the scalar-prefetched table instead.
-    """
-    nb, blk = pool.shape[:2]
-    flat = pool.reshape((nb * blk,) + pool.shape[2:])
-    idx = tbl[:, :, None] * blk + jnp.arange(blk, dtype=jnp.int32)[None, None]
-    return flat[idx.reshape(tbl.shape[0], -1)]
+def _tile_bytes(shape, dtype) -> int:
+    """VMEM bytes of an array in (sublane, 128-lane) tiles."""
+    itemsize = jnp.dtype(dtype).itemsize
+    sub = 8 * (4 // itemsize)
+    *lead, rows, cols = shape
+    return (math.prod(lead) * -(-rows // sub) * sub
+            * -(-cols // _LANES) * _LANES * itemsize)
 
 
-# ---------------------------------------------------------------------------
-# block-table GQA decode kernel
-# ---------------------------------------------------------------------------
-
-def _paged_kernel(tbl_ref, lens_ref,        # scalar prefetch
-                  q_ref, k_ref, v_ref,      # VMEM blocks
-                  o_ref,                    # output block
-                  s_scr, v_scr,             # VMEM scratch
-                  *, scale: float, blk: int, grid_k: int, hkv: int):
-    bh = pl.program_id(0)
-    ki = pl.program_id(1)
-    b = bh // hkv
-
-    @pl.when(ki == 0)
-    def _init():
-        # dead/never-stashed columns must read as masked scores and zero
-        # values so the final softmax+PV reproduces the XLA path exactly
-        s_scr[...] = jnp.full_like(s_scr, NEG_INF)
-        v_scr[...] = jnp.zeros_like(v_scr)
-
-    kvl = lens_ref[b]
-
-    @pl.when(ki * blk < kvl)                # dead tail blocks: no compute
-    def _stash():
-        q = q_ref[0].astype(jnp.float32)            # (Gp, hd)
-        k = k_ref[0, :, 0, :].astype(jnp.float32)   # (blk, hd)
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
-
-        gp = q.shape[0]
-        cols = ki * blk + jax.lax.broadcasted_iota(jnp.int32, (gp, blk), 1)
-        s = jnp.where(cols < kvl, s, NEG_INF)
-        pl.store(s_scr, (slice(None), pl.dslice(ki * blk, blk)), s)
-        pl.store(v_scr, (pl.dslice(ki * blk, blk), slice(None)),
-                 v_ref[0, :, 0, :].astype(jnp.float32))
-
-    @pl.when(ki == grid_k - 1)
-    def _finish():
-        # identical op order to layers.sdpa: f32 softmax over the full
-        # (masked) row, probs quantized to the value dtype, one PV dot.
-        # kv_len == 0 rows: uniform probs x all-zero values == exact zeros.
-        probs = jax.nn.softmax(s_scr[...], axis=-1)
-        probs = probs.astype(v_ref.dtype).astype(jnp.float32)
-        o_ref[0] = jax.lax.dot_general(
-            probs, v_scr[...], (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32).astype(o_ref.dtype)
+def _vmem_limit(scratch, blocks) -> int:
+    need = sum(_tile_bytes(s, d) for s, d in scratch) + \
+        2 * sum(_tile_bytes(s, d) for s, d in blocks)
+    return min(_VMEM_CAP, _VMEM_DEFAULT + need)
 
 
 def _live_block(lens_ref, b, ki, blk):
@@ -146,291 +110,143 @@ def _live_block(lens_ref, b, ki, blk):
     return jnp.clip(ki, 0, jnp.maximum(live - 1, 0))
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def paged_flash_decode(q, kpool, vpool, tbl, kv_len, *,
-                       interpret: bool = True):
-    """Block-table decode attention, no gather: stream each row's live
-    physical blocks straight out of the pool.
-
-    q: (B, Hq, hd); kpool/vpool: (num_blocks, block_tokens, Hkv, hd);
-    tbl: (B, max_blocks) int32; kv_len: (B,) int32.  Returns (B, Hq, hd).
-    """
-    B, Hq, hd = q.shape
-    blk, Hkv = kpool.shape[1], kpool.shape[2]
-    max_blocks = tbl.shape[1]
-    G = Hq // Hkv
-    Gp = max(8, G)
-
-    qf = q.reshape(B, Hkv, G, hd).reshape(B * Hkv, G, hd)
-    if Gp != G:
-        qf = jnp.pad(qf, ((0, 0), (0, Gp - G), (0, 0)))
-
-    kernel = functools.partial(_paged_kernel, scale=1.0 / math.sqrt(hd),
-                               blk=blk, grid_k=max_blocks, hkv=Hkv)
-
-    def q_map(bh, ki, tbl_ref, lens_ref):
-        return (bh, 0, 0)
-
-    def kv_map(bh, ki, tbl_ref, lens_ref):
-        b = bh // Hkv
-        return (tbl_ref[b, _live_block(lens_ref, b, ki, blk)], 0,
-                bh % Hkv, 0)
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(B * Hkv, max_blocks),
-        in_specs=[
-            pl.BlockSpec((1, Gp, hd), q_map),
-            pl.BlockSpec((1, blk, 1, hd), kv_map),
-            pl.BlockSpec((1, blk, 1, hd), kv_map),
-        ],
-        out_specs=pl.BlockSpec((1, Gp, hd), q_map),
-        scratch_shapes=[
-            pltpu.VMEM((Gp, max_blocks * blk), jnp.float32),
-            pltpu.VMEM((max_blocks * blk, hd), jnp.float32),
-        ],
-    )
-    out = pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B * Hkv, Gp, hd), q.dtype),
-        compiler_params=CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")),
-        interpret=interpret,
-    )(tbl.astype(jnp.int32), kv_len.astype(jnp.int32), qf, kpool, vpool)
-    return out[:, :G, :].reshape(B, Hkv, G, hd).reshape(B, Hq, hd)
-
-
-# ---------------------------------------------------------------------------
-# block-table GQA verify kernel (speculative decoding)
-# ---------------------------------------------------------------------------
-
-def _paged_verify_kernel(tbl_ref, lens_ref,
-                         q_ref, k_ref, v_ref,
-                         o_ref,
-                         s_scr, v_scr,
-                         *, scale: float, blk: int, grid_k: int, hkv: int,
-                         w: int, gp: int):
-    """The decode kernel's Sq=G tile widened to W positions: the W*Gp
-    q rows of one (row, KV-head) program share every streamed block, and
-    each position t masks its own causal frontier ``kv_len - W + t + 1``
+def _masked_scores(s, ki, kvl, *, blk, w, rows_per_pos):
+    """Mask a (W*rows_per_pos, blk) score tile: q row i is position
+    t = i // rows_per_pos, whose causal frontier is ``kvl - w + t + 1``
     — the exact column set a plain decode step at depth pos+t sees, so
-    per-position outputs are bitwise-identical to ``paged_flash_decode``
-    (masked columns underflow to exact 0 probability; value columns a
-    narrower decode never stashed multiply by that exact 0)."""
-    bh = pl.program_id(0)
+    per-position outputs equal W successive decode steps bitwise (masked
+    columns underflow to exact 0 probability)."""
+    rows = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
+    cols = ki * blk + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+    return jnp.where(cols < kvl - w + rows // rows_per_pos + 1, s, NEG_INF)
+
+
+def _softmax_pv(s_t, vals, out_dtype):
+    """Final step, in the XLA path's op order: f32 softmax over the full
+    (masked) row, probs quantized to the value dtype, one PV dot.
+    ``s_t`` is the token-major (S, rows) stash; kv_len == 0 rows give
+    uniform probs x all-zero values == exact zeros."""
+    probs = jax.nn.softmax(s_t.T, axis=-1)
+    probs = probs.astype(vals.dtype).astype(jnp.float32)
+    return jax.lax.dot_general(
+        probs, vals.astype(jnp.float32), (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32).astype(out_dtype)
+
+
+# ---------------------------------------------------------------------------
+# block-table GQA verify / decode kernel
+# ---------------------------------------------------------------------------
+
+def _paged_kernel(tbl_ref, lens_ref,        # scalar prefetch
+                  q_ref, k_ref, v_ref,      # VMEM blocks
+                  o_ref,                    # output block
+                  s_scr, v_scr,             # VMEM scratch
+                  *, scale: float, blk: int, grid_k: int, hkv: int,
+                  w: int, gp: int):
+    """One (row, page) grid step over every KV head of the page: the
+    W*Gp q rows of a head share each streamed block, so speculation adds
+    no KV traffic."""
+    b = pl.program_id(0)
     ki = pl.program_id(1)
-    b = bh // hkv
 
     @pl.when(ki == 0)
     def _init():
+        # never-stashed columns must read as masked scores and zero values
         s_scr[...] = jnp.full_like(s_scr, NEG_INF)
         v_scr[...] = jnp.zeros_like(v_scr)
 
     kvl = lens_ref[b]
 
-    @pl.when(ki * blk < kvl)
+    @pl.when(ki * blk < kvl)                # dead tail blocks: no compute
     def _stash():
-        q = q_ref[0].astype(jnp.float32)            # (W*Gp, hd)
-        k = k_ref[0, :, 0, :].astype(jnp.float32)   # (blk, hd)
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
-
-        rows = jax.lax.broadcasted_iota(jnp.int32, (w * gp, blk), 0)
-        cols = ki * blk + jax.lax.broadcasted_iota(jnp.int32, (w * gp, blk), 1)
-        limit = kvl - w + rows // gp + 1            # position t = row // gp
-        s = jnp.where(cols < limit, s, NEG_INF)
-        pl.store(s_scr, (slice(None), pl.dslice(ki * blk, blk)), s)
-        pl.store(v_scr, (pl.dslice(ki * blk, blk), slice(None)),
-                 v_ref[0, :, 0, :].astype(jnp.float32))
+        at = pl.ds(pl.multiple_of(ki * blk, blk), blk)
+        for h in range(hkv):
+            q = q_ref[0, h].astype(jnp.float32)         # (W*Gp, hd)
+            k = k_ref[0, h].astype(jnp.float32)         # (blk, hd)
+            s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                                    preferred_element_type=jnp.float32)
+            s = _masked_scores(s * scale, ki, kvl, blk=blk, w=w,
+                               rows_per_pos=gp)
+            s_scr[h, at, :] = s.T
+            v_scr[h, at, :] = v_ref[0, h]
 
     @pl.when(ki == grid_k - 1)
     def _finish():
-        probs = jax.nn.softmax(s_scr[...], axis=-1)
-        probs = probs.astype(v_ref.dtype).astype(jnp.float32)
-        o_ref[0] = jax.lax.dot_general(
-            probs, v_scr[...], (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32).astype(o_ref.dtype)
+        for h in range(hkv):
+            o_ref[0, h] = _softmax_pv(s_scr[h], v_scr[h], o_ref.dtype)
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def paged_flash_verify(q, kpool, vpool, tbl, kv_len, *,
-                       interpret: bool = True):
-    """Speculative-verify attention over the paged pool, no gather.
+@jax.jit
+def paged_flash_verify(q, kpool, vpool, tbl, kv_len):
+    """Attention for W query positions per row over the paged pool.
 
-    q: (B, W, Hq, hd) — W = 1 + k draft positions per row, whose KV the
-    caller has already written at ``kv_len - W .. kv_len - 1``;
-    kpool/vpool: (num_blocks, block_tokens, Hkv, hd); tbl: (B,
+    q: (B, W, Hq, hd) — the pending token plus W-1 draft positions (W=1
+    for a decode step), whose KV the caller has already written at
+    ``kv_len - W .. kv_len - 1``;
+    kpool/vpool: (num_blocks, Hkv, block_tokens, hd); tbl: (B,
     max_blocks) int32; kv_len: (B,) int32 TOTAL length including the W
-    new entries.  Returns (B, W, Hq, hd).  Blocks stream HBM->VMEM once
-    per (row, KV head) exactly like ``paged_flash_decode`` — W rides in
-    the q tile, not the grid, so speculation adds zero extra KV traffic.
+    new entries.  Returns (B, W, Hq, hd).  Position t masks to its own
+    frontier ``kv_len - W + t + 1``.
     """
     B, W, Hq, hd = q.shape
-    blk, Hkv = kpool.shape[1], kpool.shape[2]
+    Hkv, blk = kpool.shape[1], kpool.shape[2]
     max_blocks = tbl.shape[1]
+    S = max_blocks * blk
     G = Hq // Hkv
     Gp = max(8, G)
+    R = W * Gp
 
     qf = q.reshape(B, W, Hkv, G, hd).transpose(0, 2, 1, 3, 4)
     if Gp != G:
         qf = jnp.pad(qf, ((0, 0), (0, 0), (0, 0), (0, Gp - G), (0, 0)))
-    qf = qf.reshape(B * Hkv, W * Gp, hd)
+    qf = qf.reshape(B, Hkv, R, hd)
 
-    kernel = functools.partial(_paged_verify_kernel, scale=1.0 / math.sqrt(hd),
+    kernel = functools.partial(_paged_kernel, scale=1.0 / math.sqrt(hd),
                                blk=blk, grid_k=max_blocks, hkv=Hkv,
                                w=W, gp=Gp)
 
-    def q_map(bh, ki, tbl_ref, lens_ref):
-        return (bh, 0, 0)
+    def q_map(b, ki, tbl_ref, lens_ref):
+        return (b, 0, 0, 0)
 
-    def kv_map(bh, ki, tbl_ref, lens_ref):
-        b = bh // Hkv
-        return (tbl_ref[b, _live_block(lens_ref, b, ki, blk)], 0,
-                bh % Hkv, 0)
+    def kv_map(b, ki, tbl_ref, lens_ref):
+        return (tbl_ref[b, _live_block(lens_ref, b, ki, blk)], 0, 0, 0)
 
+    scratch = [((Hkv, S, R), jnp.float32), ((Hkv, S, hd), vpool.dtype)]
+    blocks = [((Hkv, R, hd), q.dtype)] * 2 + \
+        [((Hkv, blk, hd), kpool.dtype)] * 2
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(B * Hkv, max_blocks),
+        grid=(B, max_blocks),
         in_specs=[
-            pl.BlockSpec((1, W * Gp, hd), q_map),
-            pl.BlockSpec((1, blk, 1, hd), kv_map),
-            pl.BlockSpec((1, blk, 1, hd), kv_map),
+            pl.BlockSpec((1, Hkv, R, hd), q_map),
+            pl.BlockSpec((1, Hkv, blk, hd), kv_map),
+            pl.BlockSpec((1, Hkv, blk, hd), kv_map),
         ],
-        out_specs=pl.BlockSpec((1, W * Gp, hd), q_map),
-        scratch_shapes=[
-            pltpu.VMEM((W * Gp, max_blocks * blk), jnp.float32),
-            pltpu.VMEM((max_blocks * blk, hd), jnp.float32),
-        ],
+        out_specs=pl.BlockSpec((1, Hkv, R, hd), q_map),
+        scratch_shapes=[pltpu.VMEM(s, d) for s, d in scratch],
     )
-    out = pl.pallas_call(
+    out = pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B * Hkv, W * Gp, hd), q.dtype),
-        compiler_params=CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")),
-        interpret=interpret,
+        out_shape=jax.ShapeDtypeStruct((B, Hkv, R, hd), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=_vmem_limit(scratch, blocks)),
     )(tbl.astype(jnp.int32), kv_len.astype(jnp.int32), qf, kpool, vpool)
     out = out.reshape(B, Hkv, W, Gp, hd)[:, :, :, :G]
     return out.transpose(0, 2, 1, 3, 4).reshape(B, W, Hq, hd)
 
 
 # ---------------------------------------------------------------------------
-# block-table MLA (absorbed-latent) decode kernel
+# block-table MLA (absorbed-latent) verify / decode kernel
 # ---------------------------------------------------------------------------
 
 def _paged_mla_kernel(tbl_ref, lens_ref,
                       ql_ref, qr_ref, ckv_ref, kr_ref,
                       o_ref,
                       s_scr, ckv_scr,
-                      *, scale: float, blk: int, grid_k: int):
-    b = pl.program_id(0)
-    ki = pl.program_id(1)
-
-    @pl.when(ki == 0)
-    def _init():
-        s_scr[...] = jnp.full_like(s_scr, NEG_INF)
-        ckv_scr[...] = jnp.zeros_like(ckv_scr)
-
-    kvl = lens_ref[b]
-
-    @pl.when(ki * blk < kvl)
-    def _stash():
-        ql = ql_ref[0].astype(jnp.float32)          # (Hp, r)
-        qr = qr_ref[0].astype(jnp.float32)          # (Hp, rh)
-        ckv = ckv_ref[0].astype(jnp.float32)        # (blk, r)
-        kr = kr_ref[0].astype(jnp.float32)          # (blk, rh)
-        s = jax.lax.dot_general(ql, ckv, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32)
-        s += jax.lax.dot_general(qr, kr, (((1,), (1,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
-        s *= scale                                   # (Hp, blk)
-
-        hp = ql.shape[0]
-        cols = ki * blk + jax.lax.broadcasted_iota(jnp.int32, (hp, blk), 1)
-        s = jnp.where(cols < kvl, s, NEG_INF)
-        pl.store(s_scr, (slice(None), pl.dslice(ki * blk, blk)), s)
-        pl.store(ckv_scr, (pl.dslice(ki * blk, blk), slice(None)), ckv)
-
-    @pl.when(ki == grid_k - 1)
-    def _finish():
-        # identical op order to layers.mla_attention: f32 softmax, probs
-        # quantized to the cache dtype, one latent-context contraction
-        probs = jax.nn.softmax(s_scr[...], axis=-1)
-        probs = probs.astype(ckv_ref.dtype).astype(jnp.float32)
-        o_ref[0] = jax.lax.dot_general(
-            probs, ckv_scr[...], (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32).astype(o_ref.dtype)
-
-
-@functools.partial(jax.jit, static_argnames=("scale", "interpret"))
-def paged_flash_decode_mla(q_lat, q_rope, ckv_pool, krope_pool, tbl, kv_len,
-                           *, scale: float, interpret: bool = True):
-    """Absorbed-latent MLA decode over the paged compressed cache.
-
-    q_lat: (B, H, r) latent queries (q_nope @ Wk_b); q_rope: (B, H, rh);
-    ckv_pool: (num_blocks, block_tokens, r); krope_pool: (num_blocks,
-    block_tokens, rh); tbl: (B, max_blocks) int32; kv_len: (B,) int32.
-    Returns the latent context ctx = attn @ ckv, shape (B, H, r) — the
-    caller applies Wv_b / wo.  ``scale`` is 1/sqrt(nope_hd + rope_hd).
-    """
-    B, H, r = q_lat.shape
-    rh = q_rope.shape[-1]
-    blk = ckv_pool.shape[1]
-    max_blocks = tbl.shape[1]
-    Hp = max(8, H)
-
-    ql, qr = q_lat, q_rope
-    if Hp != H:
-        ql = jnp.pad(ql, ((0, 0), (0, Hp - H), (0, 0)))
-        qr = jnp.pad(qr, ((0, 0), (0, Hp - H), (0, 0)))
-
-    kernel = functools.partial(_paged_mla_kernel, scale=scale, blk=blk,
-                               grid_k=max_blocks)
-
-    def q_map(b, ki, tbl_ref, lens_ref):
-        return (b, 0, 0)
-
-    def kv_map(b, ki, tbl_ref, lens_ref):
-        return (tbl_ref[b, _live_block(lens_ref, b, ki, blk)], 0, 0)
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(B, max_blocks),
-        in_specs=[
-            pl.BlockSpec((1, Hp, r), q_map),
-            pl.BlockSpec((1, Hp, rh), q_map),
-            pl.BlockSpec((1, blk, r), kv_map),
-            pl.BlockSpec((1, blk, rh), kv_map),
-        ],
-        out_specs=pl.BlockSpec((1, Hp, r), q_map),
-        scratch_shapes=[
-            pltpu.VMEM((Hp, max_blocks * blk), jnp.float32),
-            pltpu.VMEM((max_blocks * blk, r), jnp.float32),
-        ],
-    )
-    ctx = pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, Hp, r), q_lat.dtype),
-        compiler_params=CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")),
-        interpret=interpret,
-    )(tbl.astype(jnp.int32), kv_len.astype(jnp.int32), ql, qr,
-      ckv_pool, krope_pool)
-    return ctx[:, :H, :]
-
-
-# ---------------------------------------------------------------------------
-# block-table MLA verify kernel (speculative decoding)
-# ---------------------------------------------------------------------------
-
-def _paged_mla_verify_kernel(tbl_ref, lens_ref,
-                             ql_ref, qr_ref, ckv_ref, kr_ref,
-                             o_ref,
-                             s_scr, ckv_scr,
-                             *, scale: float, blk: int, grid_k: int,
-                             w: int, hp: int):
+                      *, scale: float, blk: int, grid_k: int,
+                      w: int, hp: int):
     b = pl.program_id(0)
     ki = pl.program_id(1)
 
@@ -451,50 +267,52 @@ def _paged_mla_verify_kernel(tbl_ref, lens_ref,
                                 preferred_element_type=jnp.float32)
         s += jax.lax.dot_general(qr, kr, (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32)
-        s *= scale                                   # (W*Hp, blk)
-
-        rows = jax.lax.broadcasted_iota(jnp.int32, (w * hp, blk), 0)
-        cols = ki * blk + jax.lax.broadcasted_iota(jnp.int32, (w * hp, blk), 1)
-        limit = kvl - w + rows // hp + 1            # per-position frontier
-        s = jnp.where(cols < limit, s, NEG_INF)
-        pl.store(s_scr, (slice(None), pl.dslice(ki * blk, blk)), s)
-        pl.store(ckv_scr, (pl.dslice(ki * blk, blk), slice(None)), ckv)
+        s = _masked_scores(s * scale, ki, kvl, blk=blk, w=w, rows_per_pos=hp)
+        at = pl.ds(pl.multiple_of(ki * blk, blk), blk)
+        s_scr[at, :] = s.T
+        ckv_scr[at, :] = ckv_ref[0]
 
     @pl.when(ki == grid_k - 1)
     def _finish():
-        probs = jax.nn.softmax(s_scr[...], axis=-1)
-        probs = probs.astype(ckv_ref.dtype).astype(jnp.float32)
-        o_ref[0] = jax.lax.dot_general(
-            probs, ckv_scr[...], (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32).astype(o_ref.dtype)
+        # identical op order to layers.mla_attention (probs quantized to
+        # the cache dtype, one latent-context contraction), a lane tile
+        # of q rows at a time to bound the (rows, S) temporaries
+        rows = s_scr.shape[1]
+        for r0 in range(0, rows, _LANES):
+            r1 = min(rows, r0 + _LANES)
+            o_ref[0, r0:r1] = _softmax_pv(s_scr[:, r0:r1], ckv_scr[...],
+                                          o_ref.dtype)
 
 
-@functools.partial(jax.jit, static_argnames=("scale", "interpret"))
+@functools.partial(jax.jit, static_argnames=("scale",))
 def paged_flash_verify_mla(q_lat, q_rope, ckv_pool, krope_pool, tbl, kv_len,
-                           *, scale: float, interpret: bool = True):
-    """Absorbed-latent MLA speculative verify over the paged pool.
+                           *, scale: float):
+    """Absorbed-latent MLA attention for W positions per row over the
+    paged compressed cache.
 
-    q_lat: (B, W, H, r); q_rope: (B, W, H, rh); pools/tbl as in
-    ``paged_flash_decode_mla``; kv_len: (B,) TOTAL length including the
-    W freshly written latents.  Returns the latent context (B, W, H, r).
-    Each position t masks to its own frontier ``kv_len - W + t + 1`` so
-    outputs match W successive absorbed decode steps bitwise; the W
-    positions share each streamed block (no extra HBM traffic).
+    q_lat: (B, W, H, r) latent queries (q_nope @ Wk_b); q_rope: (B, W, H,
+    rh); ckv_pool: (num_blocks, block_tokens, r); krope_pool: (num_blocks,
+    block_tokens, rh); tbl: (B, max_blocks) int32; kv_len: (B,) TOTAL
+    length including the W freshly written latents.  Returns the latent
+    context ctx = attn @ ckv, shape (B, W, H, r) — the caller applies
+    Wv_b / wo.  ``scale`` is 1/sqrt(nope_hd + rope_hd).
     """
     B, W, H, r = q_lat.shape
     rh = q_rope.shape[-1]
     blk = ckv_pool.shape[1]
     max_blocks = tbl.shape[1]
+    S = max_blocks * blk
     Hp = max(8, H)
+    R = W * Hp
 
     ql, qr = q_lat, q_rope
     if Hp != H:
         ql = jnp.pad(ql, ((0, 0), (0, 0), (0, Hp - H), (0, 0)))
         qr = jnp.pad(qr, ((0, 0), (0, 0), (0, Hp - H), (0, 0)))
-    ql = ql.reshape(B, W * Hp, r)
-    qr = qr.reshape(B, W * Hp, rh)
+    ql = ql.reshape(B, R, r)
+    qr = qr.reshape(B, R, rh)
 
-    kernel = functools.partial(_paged_mla_verify_kernel, scale=scale, blk=blk,
+    kernel = functools.partial(_paged_mla_kernel, scale=scale, blk=blk,
                                grid_k=max_blocks, w=W, hp=Hp)
 
     def q_map(b, ki, tbl_ref, lens_ref):
@@ -503,28 +321,29 @@ def paged_flash_verify_mla(q_lat, q_rope, ckv_pool, krope_pool, tbl, kv_len,
     def kv_map(b, ki, tbl_ref, lens_ref):
         return (tbl_ref[b, _live_block(lens_ref, b, ki, blk)], 0, 0)
 
+    scratch = [((S, R), jnp.float32), ((S, r), ckv_pool.dtype)]
+    blocks = [((R, r), q_lat.dtype)] * 2 + [((R, rh), q_rope.dtype),
+                                            ((blk, r), ckv_pool.dtype),
+                                            ((blk, rh), krope_pool.dtype)]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(B, max_blocks),
         in_specs=[
-            pl.BlockSpec((1, W * Hp, r), q_map),
-            pl.BlockSpec((1, W * Hp, rh), q_map),
+            pl.BlockSpec((1, R, r), q_map),
+            pl.BlockSpec((1, R, rh), q_map),
             pl.BlockSpec((1, blk, r), kv_map),
             pl.BlockSpec((1, blk, rh), kv_map),
         ],
-        out_specs=pl.BlockSpec((1, W * Hp, r), q_map),
-        scratch_shapes=[
-            pltpu.VMEM((W * Hp, max_blocks * blk), jnp.float32),
-            pltpu.VMEM((max_blocks * blk, r), jnp.float32),
-        ],
+        out_specs=pl.BlockSpec((1, R, r), q_map),
+        scratch_shapes=[pltpu.VMEM(s, d) for s, d in scratch],
     )
-    ctx = pl.pallas_call(
+    ctx = pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, W * Hp, r), q_lat.dtype),
-        compiler_params=CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")),
-        interpret=interpret,
+        out_shape=jax.ShapeDtypeStruct((B, R, r), q_lat.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=_vmem_limit(scratch, blocks)),
     )(tbl.astype(jnp.int32), kv_len.astype(jnp.int32), ql, qr,
       ckv_pool, krope_pool)
     return ctx.reshape(B, W, Hp, r)[:, :, :H, :]

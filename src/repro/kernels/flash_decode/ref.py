@@ -27,18 +27,18 @@ def decode_reference(q, k, v, kv_len):
     return out.reshape(B, Hq, hd).astype(q.dtype)
 
 
-def _gather(pool, tbl):
-    nb, blk = pool.shape[:2]
-    flat = pool.reshape((nb * blk,) + pool.shape[2:])
-    idx = tbl[:, :, None] * blk + jnp.arange(blk)[None, None]
-    return flat[idx.reshape(tbl.shape[0], -1)]
+def gather_kv(pool, tbl):
+    """Per-row contiguous view (B, max_blocks*blk, [H,] d) of a pool
+    whose block-token axis is second-minor: (num_blocks, [H,] blk, d)."""
+    g = jnp.moveaxis(pool[tbl], -2, 2)          # (B, mb, blk, [H,] d)
+    return g.reshape((tbl.shape[0], -1) + g.shape[3:])
 
 
 def paged_decode_reference(q, kpool, vpool, tbl, kv_len):
     """Oracle for block-table decode: gather per-row KV views from the
-    physical pool (kpool/vpool: (num_blocks, block_tokens, Hkv, hd);
+    physical pool (kpool/vpool: (num_blocks, Hkv, block_tokens, hd);
     tbl: (B, max_blocks) int32), then standard masked decode attention."""
-    return decode_reference(q, _gather(kpool, tbl), _gather(vpool, tbl),
+    return decode_reference(q, gather_kv(kpool, tbl), gather_kv(vpool, tbl),
                             kv_len)
 
 
@@ -48,8 +48,8 @@ def paged_mla_decode_reference(q_lat, q_rope, ckv_pool, krope_pool, tbl,
 
     q_lat: (B, H, r); q_rope: (B, H, rh); pools: (num_blocks, blk, r|rh);
     returns the latent context ctx = softmax(scores) @ ckv, (B, H, r)."""
-    ckv = _gather(ckv_pool, tbl).astype(jnp.float32)     # (B, S, r)
-    kr = _gather(krope_pool, tbl).astype(jnp.float32)    # (B, S, rh)
+    ckv = gather_kv(ckv_pool, tbl).astype(jnp.float32)     # (B, S, r)
+    kr = gather_kv(krope_pool, tbl).astype(jnp.float32)    # (B, S, rh)
     s = jnp.einsum("bhr,bkr->bhk", q_lat.astype(jnp.float32), ckv)
     s += jnp.einsum("bhr,bkr->bhk", q_rope.astype(jnp.float32), kr)
     s *= scale

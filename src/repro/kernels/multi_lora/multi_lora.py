@@ -22,7 +22,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.pallas_compat import CompilerParams
+from repro.kernels.platform import pallas_call
 
 
 def _kernel(x_ref, a_ref, b_ref, m_ref, o_ref, acc_scr, *,
@@ -49,7 +49,7 @@ def _kernel(x_ref, a_ref, b_ref, m_ref, o_ref, acc_scr, *,
 
 
 def multi_lora_pallas(x, a, b, task_onehot, *, scale: float = 1.0,
-                      block_n: int = 128, interpret: bool = True):
+                      block_n: int = 128):
     """x: (N, din); a: (T, din, r); b: (T, r, dout); task_onehot: (N, T)."""
     N, din = x.shape
     T, _, r = a.shape
@@ -57,7 +57,7 @@ def multi_lora_pallas(x, a, b, task_onehot, *, scale: float = 1.0,
     block_n = min(block_n, N)
     grid = (pl.cdiv(N, block_n), T)
 
-    return pl.pallas_call(
+    return pallas_call(
         functools.partial(_kernel, n_tasks=T, scale=scale),
         grid=grid,
         in_specs=[
@@ -69,7 +69,6 @@ def multi_lora_pallas(x, a, b, task_onehot, *, scale: float = 1.0,
         out_specs=pl.BlockSpec((block_n, dout), lambda ni, t: (ni, 0)),
         out_shape=jax.ShapeDtypeStruct((N, dout), x.dtype),
         scratch_shapes=[pltpu.VMEM((block_n, dout), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
-        interpret=interpret,
     )(x, a, b, task_onehot)

@@ -32,16 +32,18 @@ def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
     return Mesh(np.asarray(devices).reshape(shape), axes)
 
 
-def make_host_mesh(model: int = 1) -> Mesh:
-    """Mesh over the real local devices (tests / examples / local fleet).
+def make_host_mesh(model: int = 1, devices=None) -> Mesh:
+    """Mesh over local devices (tests / examples / local fleet): every
+    device of the process, or exactly ``devices`` when given (a one-chip
+    fleet on a four-chip host passes ``jax.devices()[:1]``).
 
-    ``model > 1`` carves a model-parallel axis out of the host devices so
+    ``model > 1`` carves a model-parallel axis out of those devices so
     fleet members build their params and decode state sharded under
     ``sharding/rules.py`` (large-member sharding; force extra host devices
     with XLA_FLAGS=--xla_force_host_platform_device_count=N to exercise it
     on CPU)."""
     import numpy as np
-    devices = jax.devices()
+    devices = list(jax.devices() if devices is None else devices)
     if model < 1 or len(devices) < model:
         raise RuntimeError(
             f"model axis {model} needs at least {model} devices, have "

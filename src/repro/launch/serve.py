@@ -3,6 +3,10 @@ assigned-arch backends served through the full semantic-router pipeline
 with batched requests.
 
   PYTHONPATH=src python -m repro.launch.serve --requests 24
+
+``build_router`` is the programmatic entry point: it compiles a DSL
+source and serves its model profiles from a ``LocalFleet``
+(``chip_smoke.py`` calls it with published widths on one TPU).
 """
 
 from __future__ import annotations
@@ -13,6 +17,7 @@ import time
 from repro.core.dsl import compile_source
 from repro.core.router import SemanticRouter
 from repro.core.types import Message, Request
+from repro.launch.compile_cache import enable_compile_cache
 from repro.serving.fleet import LocalFleet
 
 DSL_CONFIG = '''
@@ -137,12 +142,16 @@ LANE_DEMO_REQUESTS = {
 }
 
 
-def build_router(reduced: bool = True, gen_tokens: int = 8,
+def build_router(dsl: str, *, reduced: bool, gen_tokens: int = 8,
                  classifier_backend: str = "hash",
-                 lanes=("text",), model_axis: int = 1,
                  train_adapters: bool = False,
-                 adapter_cache: str = ""):
-    cfg, diags = compile_source(build_dsl(lanes))
+                 adapter_cache: str = "", **fleet_opts):
+    """Compile ``dsl`` and serve its model profiles' archs from one
+    ``LocalFleet`` — at the reduced presets or, with ``reduced=False``,
+    at published widths.  ``fleet_opts`` go to ``LocalFleet`` (batch,
+    max_seq, model_axis, decode_impl, devices, ...).  Returns
+    ``(router, fleet)``."""
+    cfg, diags = compile_source(dsl)
     for d in diags:
         print(d)
     if classifier_backend != "hash":
@@ -173,7 +182,7 @@ def build_router(reduced: bool = True, gen_tokens: int = 8,
         spec = SpecConfig(draft_arch=draft_arch, k=sp.k,
                           adaptive=sp.adaptive, probe_every=sp.probe_every)
     fleet = LocalFleet(archs, reduced=reduced, gen_tokens=gen_tokens,
-                       model_axis=model_axis, speculative=spec)
+                       speculative=spec, **fleet_opts)
     m2a = {m: p.arch for m, p in cfg.model_profiles.items() if p.arch}
     router = SemanticRouter(cfg, call_fn=fleet.call_fn(m2a))
     # QoS: admission control samples engine load through this detector;
@@ -240,10 +249,12 @@ def main(argv=None):
                          "pass")
     args = ap.parse_args(argv)
 
+    enable_compile_cache()
     lanes = tuple(l.strip() for l in args.lanes.split(",") if l.strip())
-    router, fleet = build_router(gen_tokens=args.gen_tokens,
+    router, fleet = build_router(build_dsl(lanes), reduced=True,
+                                 gen_tokens=args.gen_tokens,
                                  classifier_backend=args.classifier_backend,
-                                 lanes=lanes, model_axis=args.model_axis,
+                                 model_axis=args.model_axis,
                                  train_adapters=args.train_adapters,
                                  adapter_cache=args.adapter_cache)
     router.policies.lint = args.lint

@@ -288,10 +288,21 @@ def mla_attention(p: dict, cfg: ModelConfig, q_nope, q_rope, c_kv, k_rope,
     out[h]   = (attn @ c_kv) @ Wv_b[h]
     """
     B, Sq, H, _ = q_nope.shape
-    Skv = c_kv.shape[1]
     scale = 1.0 / math.sqrt(cfg.nope_head_dim + cfg.rope_head_dim)
 
     q_lat = jnp.einsum("bqhn,hrn->bqhr", q_nope, p["wk_b"])      # (B,Sq,H,r)
+    ctx = mla_latent_context(q_lat, q_rope, c_kv, k_rope, scale=scale,
+                             causal=causal, q_offset=q_offset, kv_len=kv_len)
+    out = jnp.einsum("bqhr,hrv->bqhv", ctx, p["wv_b"])            # (B,Sq,H,vh)
+    return out.reshape(B, Sq, H * cfg.v_head_dim) @ p["wo"]
+
+
+def mla_latent_context(q_lat, q_rope, c_kv, k_rope, *, scale: float,
+                       causal: bool, q_offset=0, kv_len=None) -> Array:
+    """The latent context ``softmax(scores) @ c_kv``, (B,Sq,H,r), of
+    absorbed-latent attention — what the paged MLA kernel returns."""
+    B, Sq = q_lat.shape[:2]
+    Skv = c_kv.shape[1]
     s_lat = jnp.einsum("bqhr,bkr->bhqk", q_lat, c_kv,
                        preferred_element_type=jnp.float32)
     s_rope = jnp.einsum("bqhr,bkr->bhqk", q_rope, k_rope,
@@ -303,10 +314,7 @@ def mla_attention(p: dict, cfg: ModelConfig, q_nope, q_rope, c_kv, k_rope,
     mask = mask[:, None] if per_row else mask[None, None]
     logits = jnp.where(mask, logits, NEG_INF)
     probs = jax.nn.softmax(logits, axis=-1).astype(c_kv.dtype)
-
-    ctx = jnp.einsum("bhqk,bkr->bqhr", probs, c_kv)               # (B,Sq,H,r)
-    out = jnp.einsum("bqhr,hrv->bqhv", ctx, p["wv_b"])            # (B,Sq,H,vh)
-    return out.reshape(B, Sq, H * cfg.v_head_dim) @ p["wo"]
+    return jnp.einsum("bhqk,bkr->bqhr", probs, c_kv)
 
 
 # ---------------------------------------------------------------------------

@@ -147,8 +147,11 @@ def paged_supported(cfg: ModelConfig) -> bool:
 
 def _block_paged_cache(cfg: ModelConfig, spec: BlockSpec, num_blocks: int,
                        block_tokens: int, dtype):
+    # the block-token axis is second-minor in every pool: GQA pools are
+    # head-major so one page of one KV head is a contiguous (blk, hd)
+    # tile (the paged Pallas kernels DMA whole pages of every head)
     if spec.mixer == "attn":
-        kv = (num_blocks, block_tokens, cfg.n_kv_heads, cfg.head_dim)
+        kv = (num_blocks, cfg.n_kv_heads, block_tokens, cfg.head_dim)
         return {"k": jnp.zeros(kv, dtype), "v": jnp.zeros(kv, dtype)}
     if spec.mixer == "mla":
         return {
@@ -163,7 +166,7 @@ def _block_paged_cache(cfg: ModelConfig, spec: BlockSpec, num_blocks: int,
 def init_paged_cache(cfg: ModelConfig, slots: int, max_seq: int,
                      num_blocks: int, block_tokens: int) -> dict:
     """Paged serving cache: KV leaves are physical block pools
-    ``(repeats, num_blocks, block_tokens, ...)`` shared by every slot;
+    ``(repeats, num_blocks, [Hkv,] block_tokens, d)`` shared by every slot;
     ``cache["tbl"]`` (slots, max_seq // block_tokens) maps each slot's
     logical blocks to physical ones (0 = the reserved trash block).  The
     per-slot table width equals the contiguous ``max_seq``, so a gathered
@@ -207,33 +210,30 @@ def _cache_write(buf: Array, val: Array, pos) -> Array:
             b, v, (p,) + (0,) * (b.ndim - 1)))(buf, val, pos)
 
 def _paged_write(pool: Array, val: Array, tbl: Array, pos) -> Array:
-    """Scatter ``val`` (B, T, ...) into the physical block pool
-    ``(num_blocks, block_tokens, ...)`` at each row's absolute positions
-    ``pos .. pos+T`` through its block-table row ``tbl`` (B, max_blocks).
-    Positions past the table (or rows whose table maps to 0) land in the
-    reserved trash block — freed slots and pad tails write garbage
-    somewhere harmless instead of into live rows."""
-    nb, blk = pool.shape[:2]
+    """Scatter ``val`` (B, T, [H,] d) into the physical block pool
+    ``(num_blocks, [H,] block_tokens, d)`` at each row's absolute
+    positions ``pos .. pos+T`` through its block-table row ``tbl`` (B,
+    max_blocks).  Positions past the table (or rows whose table maps to
+    0) land in the reserved trash block — freed slots and pad tails write
+    garbage somewhere harmless instead of into live rows."""
+    blk = pool.shape[-2]
     B, T = val.shape[:2]
     pos = jnp.broadcast_to(jnp.asarray(pos, jnp.int32), (B,))
     positions = pos[:, None] + jnp.arange(T, dtype=jnp.int32)[None, :]
     bi = positions // blk
     phys = jnp.take_along_axis(tbl, jnp.clip(bi, 0, tbl.shape[1] - 1), axis=1)
     phys = jnp.where(bi >= tbl.shape[1], 0, phys)
-    idx = phys * blk + positions % blk                       # (B, T) flat
-    flat = pool.reshape((nb * blk,) + pool.shape[2:])
-    flat = flat.at[idx.reshape(-1)].set(
+    heads = (slice(None),) * (pool.ndim - 3)
+    return pool.at[(phys.reshape(-1),) + heads + (
+        (positions % blk).reshape(-1),)].set(
         val.astype(pool.dtype).reshape((B * T,) + val.shape[2:]))
-    return flat.reshape(pool.shape)
 
 
 def _paged_view(pool: Array, tbl: Array) -> Array:
-    """Gather each row's contiguous-layout KV view (B, max_blocks*blk, ...)
-    from the physical pool through its block table."""
-    nb, blk = pool.shape[:2]
-    flat = pool.reshape((nb * blk,) + pool.shape[2:])
-    idx = tbl[:, :, None] * blk + jnp.arange(blk, dtype=jnp.int32)[None, None]
-    return flat[idx.reshape(tbl.shape[0], -1)]
+    """Gather each row's contiguous-layout KV view (B, max_blocks*blk,
+    [H,] d) from the physical pool through its block table."""
+    g = jnp.moveaxis(pool[tbl], -2, 2)          # (B, mb, blk, [H,] d)
+    return g.reshape((tbl.shape[0], -1) + g.shape[3:])
 
 
 def _sdpa_impl(cfg, q, k, v, **kw):
@@ -248,8 +248,7 @@ def _sdpa_impl(cfg, q, k, v, **kw):
             lens = jnp.full((q.shape[0],), kv_len, jnp.int32)
         return flash_attention(q, k, v, lens, causal=kw.get("causal", False),
                                sliding_window=kw.get("sliding_window", 0),
-                               q_offset=kw.get("q_offset", 0),
-                               interpret=False)
+                               q_offset=kw.get("q_offset", 0))
     return L.sdpa(q, k, v, **kw)
 
 
@@ -302,12 +301,12 @@ def _self_attn(cfg, p, h, rope, mode, bcache, pos, bidir=False, tbl=None,
                 bcache["v"], v.astype(bcache["v"].dtype), (0, pos, 0, 0)),
         }
         return h + L.attn_out(p["attn"], out), new_cache
-    if mode == "verify":
-        # speculative verify (paged only): W tokens per row at positions
-        # pos..pos+W-1 are written, then attended exactly like W successive
-        # decode steps — position t's mask set (ik <= pos+t) equals the
-        # decode step's kv_len=pos+t+1 set, so every position's output is
-        # bitwise-identical to the non-speculative decode path's.
+    if tbl is not None:
+        # paged decode (W=1) or speculative verify (W tokens per row at
+        # positions pos..pos+W-1): the new KV is written, then position t
+        # attends to ik <= pos+t — the set a decode step at kv_len=pos+t+1
+        # sees, so every verify position is bitwise-identical to the
+        # non-speculative decode path's.
         W = q.shape[1]
         kpool = _paged_write(bcache["k"], k, tbl, pos)
         vpool = _paged_write(bcache["v"], v, tbl, pos)
@@ -319,19 +318,7 @@ def _self_attn(cfg, p, h, rope, mode, bcache, pos, bidir=False, tbl=None,
                          causal=True, q_offset=pos, kv_len=pos + W,
                          sliding_window=0)
         return h + L.attn_out(p["attn"], out), {"k": kpool, "v": vpool}
-    # decode (pos: scalar, or (B,) per-row positions for continuous batching)
-    if tbl is not None:
-        kpool = _paged_write(bcache["k"], k, tbl, pos)
-        vpool = _paged_write(bcache["v"], v, tbl, pos)
-        if cfg.decode_impl == "flash_paged":
-            from repro.kernels.flash_decode.ops import paged_flash_decode
-            out = paged_flash_decode(q[:, 0], kpool, vpool, tbl,
-                                     pos + 1)[:, None]
-        else:
-            out = L.sdpa(q, _paged_view(kpool, tbl), _paged_view(vpool, tbl),
-                         causal=False, q_offset=pos, kv_len=pos + 1,
-                         sliding_window=0)
-        return h + L.attn_out(p["attn"], out), {"k": kpool, "v": vpool}
+    # contiguous decode (pos: scalar, or (B,) per-row positions)
     if cfg.decode_impl == "shardmap" and jnp.ndim(pos) == 0:
         from repro.models import smdec
         res = smdec.gqa_decode_sm(cfg, q, k, v, bcache["k"], bcache["v"],
@@ -400,11 +387,13 @@ def _mla_attn(cfg, p, h, rope, mode, bcache, pos, tbl=None,
         }
         out = _mla_naive(cfg, mp, q_nope, q_rope, c_kv, k_rope)
         return h + out, new_cache
-    if mode == "verify":
-        # speculative verify (paged only): the W positions use the SAME
-        # absorbed program as decode (NOT _mla_naive — its reassociated
-        # latent matmul flips greedy argmax on near-ties), so position t
-        # is bitwise-identical to a decode step at kv_len = pos + t + 1.
+    # decode / verify: absorbed latent attention against the compressed
+    # cache.  Paged: decode (W=1) or speculative verify (W positions per
+    # row), both through the SAME absorbed program (NOT _mla_naive — its
+    # reassociated latent matmul flips greedy argmax on near-ties), so
+    # verify position t is bitwise-identical to a decode step at
+    # kv_len = pos + t + 1.
+    if tbl is not None:
         W = q_nope.shape[1]
         ckv_p = _paged_write(bcache["ckv"], c_kv, tbl, pos)
         krope_p = _paged_write(bcache["krope"], k_rope, tbl, pos)
@@ -423,26 +412,7 @@ def _mla_attn(cfg, p, h, rope, mode, bcache, pos, tbl=None,
                                   _paged_view(krope_p, tbl),
                                   causal=True, q_offset=pos, kv_len=pos + W)
         return h + out, {"ckv": ckv_p, "krope": krope_p}
-    # decode: absorbed latent attention against the compressed cache
-    # (pos: scalar, or (B,) per-row positions for continuous batching)
-    if tbl is not None:
-        ckv_p = _paged_write(bcache["ckv"], c_kv, tbl, pos)
-        krope_p = _paged_write(bcache["krope"], k_rope, tbl, pos)
-        if cfg.decode_impl == "flash_paged":
-            from repro.kernels.flash_decode.ops import paged_flash_decode_mla
-            B, Sq, H, _ = q_nope.shape
-            q_lat = jnp.einsum("bqhn,hrn->bqhr", q_nope, mp["wk_b"])
-            ctx = paged_flash_decode_mla(
-                q_lat[:, 0], q_rope[:, 0], ckv_p, krope_p, tbl, pos + 1,
-                scale=1.0 / math.sqrt(cfg.nope_head_dim + cfg.rope_head_dim))
-            out = jnp.einsum("bhr,hrv->bhv", ctx, mp["wv_b"])
-            out = (out.reshape(B, H * cfg.v_head_dim) @ mp["wo"])[:, None]
-        else:
-            out = L.mla_attention(mp, cfg, q_nope, q_rope,
-                                  _paged_view(ckv_p, tbl),
-                                  _paged_view(krope_p, tbl),
-                                  causal=False, q_offset=pos, kv_len=pos + 1)
-        return h + out, {"ckv": ckv_p, "krope": krope_p}
+    # contiguous decode (pos: scalar, or (B,) per-row positions)
     if cfg.decode_impl == "shardmap" and jnp.ndim(pos) == 0:
         from repro.models import smdec
         B, Sq, H, _ = q_nope.shape

@@ -199,7 +199,6 @@ def moe_ep(p: dict, cfg: ModelConfig, x: Array) -> Tuple[Array, dict]:
     mesh is installed (unit tests, single host).
     """
     from jax.sharding import PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
     from repro.sharding.ctx import current_rules
     from repro.sharding.rules import batch_axes
 
@@ -249,11 +248,11 @@ def moe_ep(p: dict, cfg: ModelConfig, x: Array) -> Tuple[Array, dict]:
     xspec = P(dp, None, None)
     e_up = P("model", None, "data")   # (E, d, f): f FSDP-sharded
     e_dn = P("model", "data", None)   # (E, f, d)
-    out, lb, zl = shard_map(
+    out, lb, zl = jax.shard_map(
         body, mesh=mesh,
         in_specs=(xspec, e_up, e_up, e_dn, P(None, None)),
         out_specs=(xspec, P(), P()),
-        check_rep=False,
+        check_vma=False,
     )(x, p["w_gate"], p["w_up"], p["w_down"], p["router"])
     aux = {"moe_lb": lb, "moe_z": zl}
     if cfg.n_shared_experts:
@@ -273,7 +272,6 @@ def moe_ep_serve(p: dict, cfg: ModelConfig, x: Array) -> Tuple[Array, dict]:
     expert weight traffic drops from |experts| to |experts| / (data*model).
     """
     from jax.sharding import PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
     from repro.sharding.ctx import current_rules
     from repro.sharding.rules import batch_axes
 
@@ -334,11 +332,11 @@ def moe_ep_serve(p: dict, cfg: ModelConfig, x: Array) -> Tuple[Array, dict]:
     xspec = P(dp, None, None)
     e_up = P("model", None, "data")
     e_dn = P("model", "data", None)
-    out, lb, zl = shard_map(
+    out, lb, zl = jax.shard_map(
         body, mesh=mesh,
         in_specs=(xspec, e_up, e_up, e_dn, P(None, None)),
         out_specs=(xspec, P(), P()),
-        check_rep=False,
+        check_vma=False,
     )(x, p["w_gate"], p["w_up"], p["w_down"], p["router"])
     aux = {"moe_lb": lb, "moe_z": zl}
     if cfg.n_shared_experts:

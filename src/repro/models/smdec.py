@@ -22,7 +22,6 @@ import math
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.models.config import ModelConfig
@@ -97,12 +96,12 @@ def gqa_decode_sm(cfg: ModelConfig, q, k_new, v_new, kc, vc, pos):
 
     tok_spec = P(dp, None, None, None)
     cache_spec = P(dp, "model", None, None)
-    out, kc2, vc2 = shard_map(
+    out, kc2, vc2 = jax.shard_map(
         body, mesh=mesh,
         in_specs=(tok_spec, tok_spec, tok_spec, cache_spec, cache_spec,
                   P(None)),
         out_specs=(tok_spec, cache_spec, cache_spec),
-        check_rep=False,
+        check_vma=False,
     )(q, k_new, v_new, kc, vc, pos[None])
     return out, kc2, vc2
 
@@ -150,11 +149,11 @@ def mla_decode_sm(cfg: ModelConfig, q_lat, q_rope, ckv_new, krope_new,
 
     qspec = P(dp, None, None, None)
     c2 = P(dp, "model", None)
-    ctx, ckv2, krope2 = shard_map(
+    ctx, ckv2, krope2 = jax.shard_map(
         body, mesh=mesh,
         in_specs=(qspec, qspec, P(dp, None, None), P(dp, None, None),
                   c2, c2, P(None)),
         out_specs=(qspec, c2, c2),
-        check_rep=False,
+        check_vma=False,
     )(q_lat, q_rope, ckv_new, krope_new, ckv, krope, pos[None])
     return ctx, ckv2, krope2

@@ -77,11 +77,16 @@ DIFFUSION_ARCHS: Dict[str, dict] = {
 }
 
 
-def _validate_decode_impl(decode_impl: Optional[str]):
+def _validate_decode_impl(decode_impl: Optional[str], model_axis: int):
     if decode_impl is not None and decode_impl not in VALID_DECODE_IMPLS:
         raise ValueError(
             f"unknown decode_impl {decode_impl!r}; valid: "
             + ", ".join(VALID_DECODE_IMPLS))
+    if decode_impl == "flash_paged" and model_axis > 1:
+        # Mosaic kernels are not partitioned by the compiler
+        raise ValueError("decode_impl='flash_paged' runs the paged Pallas "
+                         "kernels on one device; use model_axis=1 or the "
+                         "XLA decode path")
 
 
 def _spec_draft_archs() -> List[str]:
@@ -553,7 +558,11 @@ class DiffusionLane(BackendLane):
         live = [i for i, j in enumerate(self.active) if j is not None]
         if not live:
             return done
-        self.latents = self._denoise(self.latents, jnp.asarray(self.t_idx))
+        # a snapshot: the loop below bumps t_idx in place while the step
+        # may still be queued, and on CPU a numpy argument is read when
+        # the step runs, not when it is dispatched
+        self.latents = self._denoise(self.latents,
+                                     jnp.asarray(self.t_idx.copy()))
         now = time.perf_counter()
         self.decode_steps += 1
         self.slot_steps += len(live)
@@ -619,7 +628,8 @@ class LocalFleet:
                  prefill_lookahead: int = 0,
                  decode_impl: Optional[str] = None,
                  speculative: Optional[SpecConfig] = None,
-                 arch_overrides: Optional[Dict[str, dict]] = None):
+                 arch_overrides: Optional[Dict[str, dict]] = None,
+                 devices: Optional[List[object]] = None):
         """``paged`` selects the KV layout per member: "auto" (default)
         pages every arch the paged cache supports (pure attention/MLA
         stacks — SSM and cross-attention members stay contiguous), True
@@ -646,11 +656,14 @@ class LocalFleet:
         ``arch_overrides`` maps member archs to ModelConfig field
         overrides (plus ``depth_mult``, which multiplies layer-group
         repeats) applied on top of the registry config before build —
-        the speculative-decoding benchmark deepens its target with it."""
-        _validate_decode_impl(decode_impl)
+        the speculative-decoding benchmark deepens its target with it.
+
+        ``devices`` are the devices the fleet's mesh spans (default: all
+        local devices)."""
+        _validate_decode_impl(decode_impl, model_axis)
         _validate_speculative(speculative, paged=paged)
         _validate_arch_overrides(arch_overrides, list(archs))
-        self.mesh = make_host_mesh(model=model_axis)
+        self.mesh = make_host_mesh(model=model_axis, devices=devices)
         self.model_axis = model_axis
         self.gen_tokens = gen_tokens
         self.members: Dict[str, object] = {}

@@ -2,7 +2,7 @@
 
 The device side of the paged cache is a plain pytree (see
 ``model.init_paged_cache``): every KV leaf is ``(repeats, num_blocks,
-block_tokens, ...)`` and each scheduler slot owns a row of an ``(slots,
+[Hkv,] block_tokens, d)`` and each scheduler slot owns a row of an ``(slots,
 max_blocks)`` int32 block table.  This module is the HOST side: which
 physical block holds which chained prefix hash, who references it, and
 what to copy when a shared block must be written (copy-on-write).

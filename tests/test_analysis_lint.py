@@ -89,42 +89,43 @@ def test_decision_gate_is_lint_clean():
 
 
 def test_paged_flash_decode_kernels_are_lint_clean():
-    from repro.kernels.flash_decode.ops import (paged_flash_decode,
-                                                paged_flash_decode_mla)
+    """Decode is the verify kernels at W=1."""
+    from repro.kernels.flash_decode.ops import (paged_flash_verify,
+                                                paged_flash_verify_mla)
     B, nb, max_blocks, blk, Hq, Hkv, hd = 3, 10, 4, 16, 8, 2, 64
     S = max_blocks * blk
-    q = jnp.zeros((B, Hq, hd), jnp.float32)
-    kpool = jnp.zeros((nb, blk, Hkv, hd), jnp.float32)
-    vpool = jnp.zeros((nb, blk, Hkv, hd), jnp.float32)
+    q = jnp.zeros((B, 1, Hq, hd), jnp.float32)
+    kpool = jnp.zeros((nb, Hkv, blk, hd), jnp.float32)
+    vpool = jnp.zeros((nb, Hkv, blk, hd), jnp.float32)
     tbl = jnp.zeros((B, max_blocks), jnp.int32)
     kv_len = jnp.zeros((B,), jnp.int32)
-    findings = lint_fn(paged_flash_decode, q, kpool, vpool, tbl, kv_len,
+    findings = lint_fn(paged_flash_verify, q, kpool, vpool, tbl, kv_len,
                        banned_leading_shapes=[(B, S), (B * 2, S)])
     assert findings == [], [str(f) for f in findings]
 
     r, rh = 64, 32
-    ql = jnp.zeros((B, Hq, r), jnp.float32)
-    qr = jnp.zeros((B, Hq, rh), jnp.float32)
+    ql = jnp.zeros((B, 1, Hq, r), jnp.float32)
+    qr = jnp.zeros((B, 1, Hq, rh), jnp.float32)
     ckv = jnp.zeros((nb, blk, r), jnp.float32)
     kr = jnp.zeros((nb, blk, rh), jnp.float32)
     findings = lint_fn(
-        paged_flash_decode_mla, ql, qr, ckv, kr, tbl, kv_len,
+        paged_flash_verify_mla, ql, qr, ckv, kr, tbl, kv_len,
         banned_leading_shapes=[(B, S), (B * 2, S)],
         scale=1.0 / np.sqrt(96.0))
     assert findings == [], [str(f) for f in findings]
 
 
 def test_paged_flash_verify_kernels_are_lint_clean():
-    """The speculative-verify kernels inherit the decode kernels'
-    contract: no gathered (B, max_blocks*block_tokens, ...) KV copy and
-    no host callbacks — W rides the q tile, not extra KV traffic."""
+    """At W>1 the kernels keep the decode contract: no gathered (B,
+    max_blocks*block_tokens, ...) KV copy and no host callbacks — W
+    rides the q tile, not extra KV traffic."""
     from repro.kernels.flash_decode.ops import (paged_flash_verify,
                                                 paged_flash_verify_mla)
     B, nb, max_blocks, blk, Hq, Hkv, hd, W = 3, 10, 4, 16, 8, 2, 64, 5
     S = max_blocks * blk
     q = jnp.zeros((B, W, Hq, hd), jnp.float32)
-    kpool = jnp.zeros((nb, blk, Hkv, hd), jnp.float32)
-    vpool = jnp.zeros((nb, blk, Hkv, hd), jnp.float32)
+    kpool = jnp.zeros((nb, Hkv, blk, hd), jnp.float32)
+    vpool = jnp.zeros((nb, Hkv, blk, hd), jnp.float32)
     tbl = jnp.zeros((B, max_blocks), jnp.int32)
     kv_len = jnp.zeros((B,), jnp.int32)
     findings = lint_fn(paged_flash_verify, q, kpool, vpool, tbl, kv_len,
@@ -202,8 +203,8 @@ def test_verify_kernels_no_recompile_across_warm_width_buckets():
     from repro.kernels.flash_decode.ops import (paged_flash_verify,
                                                 paged_flash_verify_mla)
     B, nb, max_blocks, blk, Hq, Hkv, hd = 2, 6, 2, 16, 8, 2, 64
-    kpool = jnp.zeros((nb, blk, Hkv, hd), jnp.float32)
-    vpool = jnp.zeros((nb, blk, Hkv, hd), jnp.float32)
+    kpool = jnp.zeros((nb, Hkv, blk, hd), jnp.float32)
+    vpool = jnp.zeros((nb, Hkv, blk, hd), jnp.float32)
     ckv = jnp.zeros((nb, blk, 64), jnp.float32)
     kr = jnp.zeros((nb, blk, 32), jnp.float32)
     tbl = jnp.zeros((B, max_blocks), jnp.int32)

@@ -4,7 +4,9 @@ Covers the PR-8 tentpole and its satellites:
 
 * kernel sweeps: random block tables (shared + trash blocks), ragged
   ``kv_len`` including zero-length masked lanes, GQA and MLA variants vs
-  the gather-based oracles; non-interpret parity on real TPUs;
+  the gather-based oracles; on a TPU, both paged kernels (decode W=1,
+  verify W=3) compiled equal the XLA path bitwise and the interpreted
+  kernels within the chip-vs-host arithmetic gap;
 * the non-materialization guarantee: the paged kernels never build the
   ``(B, max_blocks*block_tokens, ...)`` gathered KV tensor;
 * fleet-level token-exactness of ``decode_impl="flash_paged"`` against
@@ -19,6 +21,7 @@ Covers the PR-8 tentpole and its satellites:
   overload probe seeing queued-but-stalled requests.
 """
 
+import functools
 import random
 import time
 
@@ -30,7 +33,15 @@ import pytest
 ATTN_ARCH = "smollm-360m"
 MLA_ARCH = "deepseek-v2-236b"
 
-ON_TPU = jax.devices()[0].platform == "tpu"
+
+@pytest.fixture(scope="module")
+def tpu():
+    """The TPU this process drives; asked inside the fixture, never at
+    import, so collecting this file touches no accelerator."""
+    dev = jax.devices()[0]
+    if dev.platform != "tpu":
+        pytest.skip("compiled-mode parity needs a TPU")
+    return dev
 
 
 # ---------------------------------------------------------------------------
@@ -60,16 +71,17 @@ def test_paged_flash_decode_kernel_sweep(rng):
     checked against the kernel's contract (exact zeros) separately — the
     oracle's all-masked softmax degenerates to a uniform average."""
     from repro.kernels.flash_decode import (paged_decode_reference,
-                                            paged_flash_decode)
+                                            paged_flash_verify)
     B, nb, max_blocks, blk, Hq, Hkv, hd = 5, 12, 4, 16, 8, 2, 64
-    kpool = jnp.asarray(rng.standard_normal((nb, blk, Hkv, hd)), jnp.float32)
-    vpool = jnp.asarray(rng.standard_normal((nb, blk, Hkv, hd)), jnp.float32)
+    kpool = jnp.asarray(rng.standard_normal((nb, Hkv, blk, hd)), jnp.float32)
+    vpool = jnp.asarray(rng.standard_normal((nb, Hkv, blk, hd)), jnp.float32)
     for seed in range(4):
         rnd = random.Random(seed)
         tbl, kv_len = _rand_case(rnd, B=B, nb=nb, max_blocks=max_blocks,
                                  blk=blk)
         q = jnp.asarray(rng.standard_normal((B, Hq, hd)), jnp.float32)
-        out = np.asarray(paged_flash_decode(q, kpool, vpool, tbl, kv_len))
+        out = np.asarray(paged_flash_verify(q[:, None], kpool, vpool, tbl,
+                                            kv_len))[:, 0]
         ref = np.asarray(paged_decode_reference(q, kpool, vpool, tbl,
                                                 kv_len))
         lens = np.asarray(kv_len)
@@ -80,7 +92,7 @@ def test_paged_flash_decode_kernel_sweep(rng):
 
 
 def test_paged_flash_decode_mla_kernel_sweep(rng):
-    from repro.kernels.flash_decode import (paged_flash_decode_mla,
+    from repro.kernels.flash_decode import (paged_flash_verify_mla,
                                             paged_mla_decode_reference)
     B, nb, max_blocks, blk, H, r, rh = 4, 10, 4, 16, 8, 64, 32
     scale = 1.0 / np.sqrt(96.0)
@@ -92,8 +104,9 @@ def test_paged_flash_decode_mla_kernel_sweep(rng):
                                  blk=blk)
         ql = jnp.asarray(rng.standard_normal((B, H, r)), jnp.float32)
         qr = jnp.asarray(rng.standard_normal((B, H, rh)), jnp.float32)
-        out = np.asarray(paged_flash_decode_mla(ql, qr, ckv, kr, tbl,
-                                                kv_len, scale=scale))
+        out = np.asarray(paged_flash_verify_mla(
+            ql[:, None], qr[:, None], ckv, kr, tbl, kv_len,
+            scale=scale))[:, 0]
         ref = np.asarray(paged_mla_decode_reference(ql, qr, ckv, kr, tbl,
                                                     kv_len, scale=scale))
         lens = np.asarray(kv_len)
@@ -103,19 +116,79 @@ def test_paged_flash_decode_mla_kernel_sweep(rng):
         assert (out[~live] == 0.0).all(), f"seed={seed}"
 
 
-@pytest.mark.skipif(not ON_TPU, reason="compiled-mode parity needs a TPU")
-def test_paged_flash_decode_compiled_matches_interpret(rng):
-    from repro.kernels.flash_decode import paged_flash_decode
+SAME_PLATFORM_ATOL = 2 ** -8
+CROSS_PLATFORM_ATOL = 2 ** -7
+
+
+def _xla_gqa(q, kpool, vpool, tbl, kv_len):
+    """The XLA serving path of a paged GQA decode/verify step."""
+    from repro.kernels.flash_decode import gather_kv
+    from repro.models import layers as L
+    return L.sdpa(q, gather_kv(kpool, tbl), gather_kv(vpool, tbl),
+                  causal=True, q_offset=kv_len - q.shape[1], kv_len=kv_len,
+                  sliding_window=0)
+
+
+def _xla_mla(ql, qr, ckv, kr, tbl, kv_len, *, scale):
+    """The XLA serving path's latent context of a paged MLA step."""
+    from repro.kernels.flash_decode import gather_kv
+    from repro.models import layers as L
+    return L.mla_latent_context(ql, qr, gather_kv(ckv, tbl),
+                                gather_kv(kr, tbl), scale=scale, causal=True,
+                                q_offset=kv_len - ql.shape[1], kv_len=kv_len)
+
+
+def test_paged_flash_decode_compiled_matches_interpret(rng, tpu):
+    """Both paged kernels at W=1 (decode) and W=3 (verify) on bf16
+    inputs, the serving dtype.
+
+    The kernel keeps the XLA serving path's op order, so on each platform
+    it must match that path to within ``SAME_PLATFORM_ATOL``: a summation
+    order that moves one probability across a bf16 rounding boundary
+    flips an output's last bit (2^-9 at |out| < 1: the interpreted GQA
+    verify kernel on this input, on CPU); on a TPU v5e 20 random inputs
+    per case read 0.  An off-by-one frontier at kv_len 50 moves outputs
+    by ~1/50, a wrong page by O(1).  Compiled vs interpreted compares
+    XLA:TPU with XLA:CPU arithmetic (exp, reduction order) on top: the
+    same 80 inputs differed by at most 2^-7, hence ``CROSS_PLATFORM_ATOL``.
+    f32 inputs would measure the chip's default matmul precision instead
+    (bf16 operands; 8.4e-3)."""
+    from repro.kernels.flash_decode import (paged_flash_verify,
+                                            paged_flash_verify_mla)
     B, nb, max_blocks, blk, Hq, Hkv, hd = 3, 8, 4, 16, 8, 2, 64
-    kpool = jnp.asarray(rng.standard_normal((nb, blk, Hkv, hd)), jnp.float32)
-    vpool = jnp.asarray(rng.standard_normal((nb, blk, Hkv, hd)), jnp.float32)
-    rnd = random.Random(7)
-    tbl, kv_len = _rand_case(rnd, B=B, nb=nb, max_blocks=max_blocks, blk=blk)
-    q = jnp.asarray(rng.standard_normal((B, Hq, hd)), jnp.float32)
-    a = paged_flash_decode(q, kpool, vpool, tbl, kv_len, interpret=True)
-    b = paged_flash_decode(q, kpool, vpool, tbl, kv_len, interpret=False)
-    np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=2e-5,
-                               rtol=2e-5)
+    r, rh = 64, 32
+    scale = 1.0 / np.sqrt(96.0)
+    tbl, kv_len = _rand_case(random.Random(7), B=B, nb=nb,
+                             max_blocks=max_blocks, blk=blk)
+    f = lambda *s: jnp.asarray(rng.standard_normal(s), jnp.bfloat16)
+    kpool, vpool = f(nb, Hkv, blk, hd), f(nb, Hkv, blk, hd)
+    ckv, kr = f(nb, blk, r), f(nb, blk, rh)
+    cpu = jax.devices("cpu")[0]
+    for W in (1, 3):
+        lens = jnp.maximum(kv_len, W)        # verify needs W written entries
+        cases = {
+            f"gqa W={W}": (paged_flash_verify, _xla_gqa,
+                           (f(B, W, Hq, hd), kpool, vpool), {}),
+            f"mla W={W}": (paged_flash_verify_mla, _xla_mla,
+                           (f(B, W, Hq, r), f(B, W, Hq, rh), ckv, kr),
+                           {"scale": scale}),
+        }
+        for name, (fn, xla, args, kw) in cases.items():
+            on_tpu = jax.device_put(args + (tbl, lens), tpu)
+            on_cpu = jax.device_put(args + (tbl, lens), cpu)
+            compiled = np.asarray(fn(*on_tpu, **kw), np.float32)
+            interpreted = np.asarray(fn(*on_cpu, **kw), np.float32)
+            xla_tpu = jax.jit(functools.partial(xla, **kw))(*on_tpu)
+            xla_cpu = jax.jit(functools.partial(xla, **kw))(*on_cpu)
+            np.testing.assert_allclose(
+                compiled, np.asarray(xla_tpu, np.float32),
+                atol=SAME_PLATFORM_ATOL, rtol=0, err_msg=f"{name} TPU")
+            np.testing.assert_allclose(
+                interpreted, np.asarray(xla_cpu, np.float32),
+                atol=SAME_PLATFORM_ATOL, rtol=0, err_msg=f"{name} CPU")
+            np.testing.assert_allclose(compiled, interpreted,
+                                       atol=CROSS_PLATFORM_ATOL, rtol=0,
+                                       err_msg=name)
 
 
 def _gathered_shapes(B, max_blocks, blk):
@@ -138,15 +211,15 @@ def test_paged_flash_decode_never_materializes_gathered_kv(rng):
     the (B, max_blocks*block_tokens, ...) gathered-KV shape — KV moves
     block-by-block through the scalar-prefetched table, never as a
     per-row contiguous copy."""
-    from repro.kernels.flash_decode.ops import paged_flash_decode
+    from repro.kernels.flash_decode.ops import paged_flash_verify
     B, nb, max_blocks, blk, Hq, Hkv, hd = 3, 10, 4, 16, 8, 2, 64
-    q = jnp.zeros((B, Hq, hd), jnp.float32)
-    kpool = jnp.zeros((nb, blk, Hkv, hd), jnp.float32)
-    vpool = jnp.zeros((nb, blk, Hkv, hd), jnp.float32)
+    q = jnp.zeros((B, 1, Hq, hd), jnp.float32)
+    kpool = jnp.zeros((nb, Hkv, blk, hd), jnp.float32)
+    vpool = jnp.zeros((nb, Hkv, blk, hd), jnp.float32)
     tbl = jnp.zeros((B, max_blocks), jnp.int32)
     kv_len = jnp.zeros((B,), jnp.int32)
     jaxpr = jax.make_jaxpr(
-        lambda *a: paged_flash_decode(*a))(q, kpool, vpool, tbl, kv_len)
+        lambda *a: paged_flash_verify(*a))(q, kpool, vpool, tbl, kv_len)
     bad = _gathered_shapes(B, max_blocks, blk)
     for eqn in _walk_eqns(jaxpr.jaxpr):
         for var in eqn.outvars:

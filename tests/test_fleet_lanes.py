@@ -186,7 +186,11 @@ def test_overlong_history_keeps_the_newest_turn(fleet):
 _SHARDED_SNIPPET = """
 import jax
 assert jax.device_count() == 4, jax.device_count()
+from repro.launch.mesh import make_host_mesh
 from repro.serving.fleet import LocalFleet
+# a one-chip fleet on a four-chip host spans exactly the device it is given
+one = make_host_mesh(devices=jax.devices()[1:2])
+assert list(one.devices.flat) == jax.devices()[1:2], one.devices
 fleet = LocalFleet(["qwen3-moe-235b-a22b"], reduced=True, batch=2,
                    max_seq=64, gen_tokens=4, model_axis=2)
 assert dict(fleet.mesh.shape) == {"data": 2, "model": 2}
@@ -219,3 +223,12 @@ def test_model_axis_exceeding_devices_raises():
     from repro.launch.mesh import make_host_mesh
     with pytest.raises(RuntimeError, match="model axis"):
         make_host_mesh(model=4096)
+
+
+def test_host_mesh_takes_the_given_devices():
+    import jax
+    from repro.launch.mesh import make_host_mesh
+    one = jax.devices()[:1]
+    assert list(make_host_mesh(devices=one).devices.flat) == one
+    with pytest.raises(RuntimeError, match="model axis 2 needs"):
+        make_host_mesh(model=2, devices=one)

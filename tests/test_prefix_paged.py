@@ -216,15 +216,15 @@ def test_blockpool_random_refcount_sweep():
 def test_paged_flash_decode_vs_references(rng):
     from repro.kernels.flash_decode import (decode_reference, gather_kv,
                                             paged_decode_reference,
-                                            paged_flash_decode)
+                                            paged_flash_verify)
     B, nb, blk, Hq, Hkv, hd = 3, 10, 16, 8, 2, 64
     q = jnp.asarray(rng.standard_normal((B, Hq, hd)), jnp.float32)
-    kpool = jnp.asarray(rng.standard_normal((nb, blk, Hkv, hd)), jnp.float32)
-    vpool = jnp.asarray(rng.standard_normal((nb, blk, Hkv, hd)), jnp.float32)
+    kpool = jnp.asarray(rng.standard_normal((nb, Hkv, blk, hd)), jnp.float32)
+    vpool = jnp.asarray(rng.standard_normal((nb, Hkv, blk, hd)), jnp.float32)
     # each row maps 4 blocks, deliberately scattered and overlapping
     tbl = jnp.asarray([[1, 5, 2, 9], [3, 1, 7, 4], [8, 6, 1, 2]], jnp.int32)
     kv_len = jnp.asarray([64, 50, 17], jnp.int32)
-    out = paged_flash_decode(q, kpool, vpool, tbl, kv_len)
+    out = paged_flash_verify(q[:, None], kpool, vpool, tbl, kv_len)[:, 0]
     ref = paged_decode_reference(q, kpool, vpool, tbl, kv_len)
     np.testing.assert_allclose(out, ref, atol=2e-5, rtol=2e-5)
     # the paged path equals the contiguous oracle on the gathered view

@@ -3,9 +3,9 @@
 Covers the tentpole and its satellites:
 
 * kernel sweeps: ``paged_flash_verify`` / ``paged_flash_verify_mla``
-  match W successive paged flash-decode steps BITWISE (each verify
-  position's causal frontier equals the corresponding decode step's
-  ``kv_len``), including W=1 degenerating to plain decode;
+  at width W match W successive W=1 calls (decode steps) BITWISE (each
+  verify position's causal frontier equals the corresponding decode
+  step's ``kv_len``);
 * fleet-level token-exactness: the speculative path emits IDENTICAL
   tokens to the non-speculative path on xla AND flash_paged decode, on
   the GQA arch AND the MLA+MoE arch;
@@ -89,11 +89,10 @@ def _tbl_and_lens(rng, *, B, nb, max_blocks, blk, W):
 
 
 def test_paged_flash_verify_bitwise_matches_decode_steps(rng):
-    from repro.kernels.flash_decode import (paged_flash_decode,
-                                            paged_flash_verify)
+    from repro.kernels.flash_decode import paged_flash_verify
     B, nb, max_blocks, blk, Hq, Hkv, hd, W = 4, 12, 4, 16, 8, 2, 64, 3
-    kpool = jnp.asarray(rng.standard_normal((nb, blk, Hkv, hd)), jnp.float32)
-    vpool = jnp.asarray(rng.standard_normal((nb, blk, Hkv, hd)), jnp.float32)
+    kpool = jnp.asarray(rng.standard_normal((nb, Hkv, blk, hd)), jnp.float32)
+    vpool = jnp.asarray(rng.standard_normal((nb, Hkv, blk, hd)), jnp.float32)
     tbl, kv_len = _tbl_and_lens(rng, B=B, nb=nb, max_blocks=max_blocks,
                                 blk=blk, W=W)
     q = jnp.asarray(rng.standard_normal((B, W, Hq, hd)), jnp.float32)
@@ -102,19 +101,14 @@ def test_paged_flash_verify_bitwise_matches_decode_steps(rng):
     for t in range(W):
         # position t's frontier == the decode step that would see
         # kv_len - (W - 1 - t) written entries
-        step = np.asarray(paged_flash_decode(
-            q[:, t], kpool, vpool, tbl, kv_len - (W - 1 - t)))
-        np.testing.assert_array_equal(out[:, t], step, err_msg=f"t={t}")
-    # W == 1 degenerates to plain decode
-    one = np.asarray(paged_flash_verify(q[:, :1], kpool, vpool, tbl, kv_len))
-    np.testing.assert_array_equal(
-        one[:, 0], np.asarray(paged_flash_decode(q[:, 0], kpool, vpool,
-                                                 tbl, kv_len)))
+        step = np.asarray(paged_flash_verify(
+            q[:, t:t + 1], kpool, vpool, tbl, kv_len - (W - 1 - t)))
+        np.testing.assert_array_equal(out[:, t], step[:, 0],
+                                      err_msg=f"t={t}")
 
 
 def test_paged_flash_verify_mla_bitwise_matches_decode_steps(rng):
-    from repro.kernels.flash_decode import (paged_flash_decode_mla,
-                                            paged_flash_verify_mla)
+    from repro.kernels.flash_decode import paged_flash_verify_mla
     B, nb, max_blocks, blk, H, r, rh, W = 3, 10, 4, 16, 8, 64, 32, 4
     scale = 1.0 / np.sqrt(96.0)
     ckv = jnp.asarray(rng.standard_normal((nb, blk, r)), jnp.float32)
@@ -127,10 +121,11 @@ def test_paged_flash_verify_mla_bitwise_matches_decode_steps(rng):
                                             scale=scale))
     assert out.shape == (B, W, H, r)
     for t in range(W):
-        step = np.asarray(paged_flash_decode_mla(
-            ql[:, t], qr[:, t], ckv, kr, tbl, kv_len - (W - 1 - t),
-            scale=scale))
-        np.testing.assert_array_equal(out[:, t], step, err_msg=f"t={t}")
+        step = np.asarray(paged_flash_verify_mla(
+            ql[:, t:t + 1], qr[:, t:t + 1], ckv, kr, tbl,
+            kv_len - (W - 1 - t), scale=scale))
+        np.testing.assert_array_equal(out[:, t], step[:, 0],
+                                      err_msg=f"t={t}")
 
 
 # ---------------------------------------------------------------------------
@@ -301,6 +296,10 @@ def test_decode_impl_validated_at_construction():
     with pytest.raises(ValueError, match=r"flash_paged"):
         LocalFleet([ATTN_ARCH], reduced=True, paged=True,
                    decode_impl="flashy_paged")
+    # the paged Pallas kernels run on one device: no model-axis sharding
+    with pytest.raises(ValueError, match=r"model_axis=1"):
+        LocalFleet([ATTN_ARCH], reduced=True, paged=True,
+                   decode_impl="flash_paged", model_axis=2)
 
 
 def test_speculative_validated_at_construction():

@@ -10,8 +10,8 @@ import pytest
 
 def test_serve_fleet_end_to_end():
     from repro.core.types import Message, Request
-    from repro.launch.serve import build_router
-    router, fleet = build_router(reduced=True, gen_tokens=4)
+    from repro.launch.serve import build_dsl, build_router
+    router, fleet = build_router(build_dsl(), reduced=True, gen_tokens=4)
     cases = [
         ("Prove the convergence of the geometric series using real "
          "analysis", "hard_math"),
