@@ -4,23 +4,50 @@ Prometheus-style counters/histograms (in-process; the export surface is a
 text scrape endpoint format) and an OpenTelemetry-like span model with the
 paper's hierarchy: root -> signal spans -> decision span -> plugin spans ->
 upstream span.
+
+``trace_span`` puts a span on the profiler's clock (``vsr:<name>``): the
+pipeline's stages and the fleet's lane step open one, so a profiler
+trace shows which host phase the device waited on.  With no profiler
+session active a span costs one native call.
 """
 
 from __future__ import annotations
 
 import threading
 import time
-from collections import defaultdict
+from collections import defaultdict, deque
 from contextlib import contextmanager
 from dataclasses import dataclass, field
-from typing import Any, Dict, List, Optional
+from typing import Any, Deque, Dict, List, Optional
+
+from jax.profiler import TraceAnnotation
+
+# recent values a histogram keeps for ``percentile``; its count and sum
+# cover every value observed
+HIST_RECENT = 4096
+
+
+class Hist:
+    """Exact count and sum of every observation, plus the most recent
+    ``HIST_RECENT`` values."""
+    __slots__ = ("count", "sum", "recent")
+
+    def __init__(self):
+        self.count = 0
+        self.sum = 0.0
+        self.recent: Deque[float] = deque(maxlen=HIST_RECENT)
+
+    def add(self, value: float):
+        self.count += 1
+        self.sum += value
+        self.recent.append(value)
 
 
 class Metrics:
     def __init__(self):
         self._lock = threading.Lock()
         self.counters: Dict[str, float] = defaultdict(float)
-        self.hists: Dict[str, List[float]] = defaultdict(list)
+        self.hists: Dict[str, Hist] = defaultdict(Hist)
         self.gauges: Dict[str, float] = {}
 
     def inc(self, name: str, value: float = 1.0, **labels):
@@ -29,7 +56,7 @@ class Metrics:
 
     def observe(self, name: str, value: float, **labels):
         with self._lock:
-            self.hists[self._key(name, labels)].append(value)
+            self.hists[self._key(name, labels)].add(value)
 
     def gauge(self, name: str, value: float, **labels):
         """Set-to-latest metric (e.g. overload state, queue depth)."""
@@ -44,7 +71,9 @@ class Metrics:
         return f"{name}{{{lab}}}"
 
     def percentile(self, name: str, p: float, **labels) -> float:
-        vals = sorted(self.hists.get(self._key(name, labels), []))
+        """``p``-th percentile of the histogram's recent values."""
+        h = self.hists.get(self._key(name, labels))
+        vals = sorted(h.recent) if h is not None else []
         if not vals:
             return 0.0
         idx = min(len(vals) - 1, int(p / 100 * len(vals)))
@@ -57,11 +86,11 @@ class Metrics:
             lines.append(f"vsr_{k} {v}")
         for k, v in sorted(self.gauges.items()):
             lines.append(f"vsr_{k} {v}")
-        for k, vals in sorted(self.hists.items()):
+        for k, h in sorted(self.hists.items()):
             base, _, lab = k.partition("{")
             lab = ("{" + lab) if lab else ""
-            lines.append(f"vsr_{base}_count{lab} {len(vals)}")
-            lines.append(f"vsr_{base}_sum{lab} {sum(vals):.6f}")
+            lines.append(f"vsr_{base}_count{lab} {h.count}")
+            lines.append(f"vsr_{base}_sum{lab} {h.sum:.6f}")
         return "\n".join(lines)
 
 
@@ -92,12 +121,17 @@ class Span:
         for c in self.children:
             yield from c.flatten(depth + 1)
 
-    def render(self) -> str:
-        return "\n".join(f"{'  ' * d}{s.name} {s.duration_ms:.2f}ms "
-                         f"{s.attributes}" for d, s in self.flatten())
-
 
 METRICS = Metrics()
+
+
+def trace_span(name: str, **meta) -> TraceAnnotation:
+    """Context manager: a span named ``vsr:<name>`` on the profiler's
+    clock, with ``meta`` (arch, step, live rows, rid, ...) as its stats.
+    The stats are encoded only while a profiler session records.  A span
+    whose name ends in ``.fetch`` is the host blocked on a device
+    result."""
+    return TraceAnnotation("vsr:" + name, **meta)
 
 
 @contextmanager
@@ -107,11 +141,13 @@ def stage_scope(parent: Optional[Span], name: str, *,
 
     Creates ``name`` as a child of ``parent`` (or a standalone root span
     when ``parent`` is None), finishes it on exit, and records the stage
-    duration into ``metric`` labelled by the stage name."""
+    duration into ``metric`` labelled by the stage name.  The stage also
+    opens ``vsr:<name>`` on the profiler's clock."""
     span = parent.child(name, **attrs) if parent is not None \
         else Span(name, attributes=dict(attrs))
     try:
-        yield span
+        with trace_span(name):
+            yield span
     finally:
         span.finish()
         METRICS.observe(metric, span.duration_ms,

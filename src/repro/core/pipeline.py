@@ -531,6 +531,7 @@ def stage_dispatch(router, ctxs: List[RequestContext]):
                 continue
             resp, ep = out
             span.finish(endpoint=ep.name, provider=ep.provider)
+            _fleet_spans(span, resp.usage)
             c.response = resp
             if affinity > 0:
                 # the serving engine now caches this conversation's
@@ -551,6 +552,24 @@ def stage_dispatch(router, ctxs: List[RequestContext]):
             METRICS.inc("model_requests_total", model=model)
             METRICS.inc("tokens_total",
                         resp.usage.get("completion_tokens", 0), model=model)
+
+
+def _fleet_spans(upstream: Span, usage: dict):
+    """The fleet's split of a request's service time, as children of its
+    ``upstream`` span: ``fleet:queue`` (submit -> prefill begins),
+    ``fleet:prefill`` (-> first token) and ``fleet:decode`` (-> last
+    token).  Queue + prefill is the transport's TTFT; the three sum to its
+    service time.  They are laid end to end, ending with ``upstream``."""
+    if "vsr_queue_ms" not in usage:
+        return
+    service = usage["vsr_service_ms"]
+    parts = (("fleet:queue", usage["vsr_queue_ms"]),
+             ("fleet:prefill", usage["vsr_prefill_ms"]),
+             ("fleet:decode", service - usage["vsr_ttft_ms"]))
+    t = upstream.end - service / 1e3
+    for name, ms in parts:
+        upstream.children.append(Span(name, start=t, end=t + ms / 1e3))
+        t += ms / 1e3
 
 
 def stage_response_plugins(router, ctxs: List[RequestContext]):

@@ -324,6 +324,10 @@ class ARLane(BackendLane):
             "ttft_ms": seq.ttft_ms,
             "tpot_ms": seq.tpot_ms,
             "service_ms": (seq.t_done - seq.t_submit) * 1e3,
+            # ttft = queue (submit -> prefill begins) + prefill (-> first
+            # token)
+            "queue_ms": (seq.t_begin - seq.t_submit) * 1e3,
+            "prefill_ms": (seq.t_first - seq.t_begin) * 1e3,
             "lane": self.modality,
         }
 
@@ -982,19 +986,22 @@ class LocalFleet:
             for extra in ("image", "transcript"):
                 if extra in out:
                     message[extra] = out[extra]
+            # prompt_tokens counts the JOINED conversation, same text the
+            # scheduler generated from
+            usage = {"prompt_tokens": len(prompt) // 4,
+                     "completion_tokens": len(out["tokens"]),
+                     # per-request transport service time: the pipeline
+                     # attributes THIS to latency-aware selection instead
+                     # of batch wall clock
+                     "vsr_service_ms": round(out["service_ms"], 3),
+                     "vsr_ttft_ms": round(out["ttft_ms"], 3),
+                     "vsr_lane": out.get("lane", "text")}
+            if "queue_ms" in out:        # autoregressive lanes
+                usage["vsr_queue_ms"] = round(out["queue_ms"], 3)
+                usage["vsr_prefill_ms"] = round(out["prefill_ms"], 3)
             return {"choices": [{"message": message,
                                  "finish_reason": "stop"}],
-                    "model": model,
-                    # prompt_tokens counts the JOINED conversation, same
-                    # text the scheduler generated from
-                    "usage": {"prompt_tokens": len(prompt) // 4,
-                              "completion_tokens": len(out["tokens"]),
-                              # per-request transport service time: the
-                              # pipeline attributes THIS to latency-aware
-                              # selection instead of batch wall clock
-                              "vsr_service_ms": round(out["service_ms"], 3),
-                              "vsr_ttft_ms": round(out["ttft_ms"], 3),
-                              "vsr_lane": out.get("lane", "text")}}
+                    "model": model, "usage": usage}
 
         def call(ep, payload, headers):
             model, arch, prompt, prio, slo = _resolve(payload)

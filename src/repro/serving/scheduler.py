@@ -61,7 +61,7 @@ from typing import Deque, List, Optional
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core.observability import METRICS
+from repro.core.observability import METRICS, trace_span
 from repro.core.prefix import chain_hashes
 from repro.serving.paged import BlockPool
 
@@ -125,6 +125,7 @@ class SequenceState:
     max_new: int                    # tokens still to generate at submit
     t_submit: float
     slot: int = -1                  # -1 while queued
+    t_begin: float = 0.0            # first taken off the queue
     t_first: float = 0.0            # first-token wall clock
     t_done: float = 0.0
     out: List[int] = field(default_factory=list)
@@ -218,11 +219,15 @@ class PrefillWorker:
         if self.current is None:
             if not s.queue or not self._may_begin():
                 return False
-            job = self._begin(s.queue[0])
+            t = time.perf_counter()
+            with trace_span("prefill.admit", rid=s.queue[0].rid):
+                job = self._begin(s.queue[0])
             if job is None:          # pool cannot hold the row: retry later
                 METRICS.inc("paged_admit_stall_total", arch=s.m.arch)
                 return False
             s.queue.popleft()
+            if job.seq.t_begin == 0.0:   # resumes keep their first begin
+                job.seq.t_begin = t
             self.current = job
         self._chunk_step(self.current)
         self.prefills += 1
@@ -304,17 +309,19 @@ class PrefillWorker:
             # contiguous: one monolithic bucketed prefill into a fresh
             # batch-1 cache, merged into the shared cache at binding
             n = job.plen
-            width = bucket_len(n, m.prompt_cap, exact=m.exact_prefill)
-            toks = np.zeros((1, width), np.int32)
-            toks[0, :n] = seq.ids
-            lens = np.asarray([n], np.int32)
-            args = [m.params, jnp.asarray(toks), jnp.asarray(lens),
-                    s._row_cache0]
-            if s._make_cross is not None:
-                args.append(seq.cross if seq.cross is not None
-                            else s._make_cross(1))
-            nxt, job.row_cache = m.prefill_row(*args)
-            job.first = int(np.asarray(nxt)[0])
+            with trace_span("prefill.launch"):
+                width = bucket_len(n, m.prompt_cap, exact=m.exact_prefill)
+                toks = np.zeros((1, width), np.int32)
+                toks[0, :n] = seq.ids
+                lens = np.asarray([n], np.int32)
+                args = [m.params, jnp.asarray(toks), jnp.asarray(lens),
+                        s._row_cache0]
+                if s._make_cross is not None:
+                    args.append(seq.cross if seq.cross is not None
+                                else s._make_cross(1))
+                nxt, job.row_cache = m.prefill_row(*args)
+            with trace_span("prefill.fetch"):
+                job.first = int(np.asarray(nxt)[0])
             seq.prefill_tokens = n
             s.prefill_tokens += n
             return
@@ -322,16 +329,17 @@ class PrefillWorker:
         clen = n - job.start
         if self.chunk is not None:
             clen = min(clen, self.chunk)
-        width = bucket_len(clen, m.prompt_cap, exact=False)
-        toks = np.zeros((1, width), np.int32)
-        toks[0, :clen] = seq.ids[job.start:job.start + clen]
-        lens = np.asarray([clen], np.int32)
-        starts = np.asarray([job.start], np.int32)
-        fn = m.prefill_paged_fresh if job.start == 0 \
-            else m.prefill_paged_suffix
-        nxt, s.cache = fn(m.params, jnp.asarray(toks), jnp.asarray(lens),
-                          jnp.asarray(starts), jnp.asarray(job.trow[None]),
-                          s.cache)
+        with trace_span("prefill.launch"):
+            width = bucket_len(clen, m.prompt_cap, exact=False)
+            toks = np.zeros((1, width), np.int32)
+            toks[0, :clen] = seq.ids[job.start:job.start + clen]
+            lens = np.asarray([clen], np.int32)
+            starts = np.asarray([job.start], np.int32)
+            fn = m.prefill_paged_fresh if job.start == 0 \
+                else m.prefill_paged_suffix
+            nxt, s.cache = fn(m.params, jnp.asarray(toks), jnp.asarray(lens),
+                              jnp.asarray(starts),
+                              jnp.asarray(job.trow[None]), s.cache)
         job.start += clen
         seq.prefill_tokens += clen
         s.prefill_tokens += clen
@@ -339,7 +347,8 @@ class PrefillWorker:
         if job.start >= n:
             # intermediate chunk samples are discarded; the final chunk
             # samples at the prompt's true last position
-            job.first = int(np.asarray(nxt)[0])
+            with trace_span("prefill.fetch"):
+                job.first = int(np.asarray(nxt)[0])
 
     def _complete(self, job: PrefillJob):
         s = self.sched
@@ -506,7 +515,8 @@ class DraftWorker:
             self.rt.params, jnp.asarray(buf), jnp.asarray(lag), self.cache,
             steps=W)
         self.proposals += 1
-        return np.asarray(props)
+        with trace_span("spec.draft.fetch"):
+            return np.asarray(props)
 
     def commit(self, slot: int, W: int):
         """After a verify round: the draft wrote W entries from its old
@@ -655,15 +665,19 @@ class DecodeScheduler:
         prefill worker within its budget, then ONE batched decode step
         over the in-flight batch.  Returns sequences finished this step."""
         done: List[SequenceState] = []
-        self._admit(done)
-        live = [i for i, s in enumerate(self.active) if s is not None]
-        if live:
-            self._decode(live, done)
-        for seq in done:
-            self._finished[seq.rid] = seq
-            while len(self._finished) > self._finished_cap:
-                self._finished.popitem(last=False)
-            METRICS.observe("fleet_ttft_ms", seq.ttft_ms, arch=self.m.arch)
+        with trace_span("sched.step", arch=self.m.arch,
+                        step=self.decode_steps,
+                        live=self.slots - self.active.count(None)):
+            self._admit(done)
+            live = [i for i, s in enumerate(self.active) if s is not None]
+            if live:
+                self._decode(live, done)
+            for seq in done:
+                self._finished[seq.rid] = seq
+                while len(self._finished) > self._finished_cap:
+                    self._finished.popitem(last=False)
+                METRICS.observe("fleet_ttft_ms", seq.ttft_ms,
+                                arch=self.m.arch)
         return done
 
     def drain(self) -> List[SequenceState]:
@@ -704,30 +718,33 @@ class DecodeScheduler:
         already resident, so the victim is never parked speculatively."""
         m = self.m
         w = self.prefill
-        while w.ready:
-            if None not in self.active:
-                if not self._try_preempt_for(w.ready[0].seq):
-                    break
-            slot = self.active.index(None)
-            job = w.ready.popleft()
-            seq = job.seq
-            if self.paged:
-                self.row_blocks[slot] = job.row
-                self.tbl[slot] = job.trow
-            else:
-                self.cache = m.merge_row(self.cache, job.row_cache, slot)
-            seq.slot = slot
-            self.pos[slot] = job.plen
-            self.last_tok[slot] = job.first
-            self.active[slot] = seq
-            if self.drafter is not None:
-                self.drafter.reset_slot(slot)
-            self.admitted += 1
-            if seq.parks == 0:       # a resume is not a new prompt
-                m.prompts_in += 1
-            m.tokens_out += 1
-            if len(seq.out) >= seq.max_new:
-                self._finish(seq, done)
+        if not w.ready:
+            return
+        with trace_span("sched.bind"):
+            while w.ready:
+                if None not in self.active:
+                    if not self._try_preempt_for(w.ready[0].seq):
+                        break
+                slot = self.active.index(None)
+                job = w.ready.popleft()
+                seq = job.seq
+                if self.paged:
+                    self.row_blocks[slot] = job.row
+                    self.tbl[slot] = job.trow
+                else:
+                    self.cache = m.merge_row(self.cache, job.row_cache, slot)
+                seq.slot = slot
+                self.pos[slot] = job.plen
+                self.last_tok[slot] = job.first
+                self.active[slot] = seq
+                if self.drafter is not None:
+                    self.drafter.reset_slot(slot)
+                self.admitted += 1
+                if seq.parks == 0:       # a resume is not a new prompt
+                    m.prompts_in += 1
+                m.tokens_out += 1
+                if len(seq.out) >= seq.max_new:
+                    self._finish(seq, done)
 
     def _preempt_candidate(self, seq: SequenceState) \
             -> Optional[SequenceState]:
@@ -796,30 +813,34 @@ class DecodeScheduler:
         # all-trash table row, so their garbage KV writes land in the
         # trash block / an overwritten row, never in a live sequence
         assert not set(dead) & set(live)
-        self.cache["pos"] = jnp.asarray(self.pos, jnp.int32)
-        if self.paged:
-            self.cache["tbl"] = jnp.asarray(self.tbl)
-        toks = jnp.asarray(self.last_tok[:, None])
-        nxt, self.cache = m.decode_rows(m.params, toks, self.cache)
-        nxt = np.asarray(nxt)
-        self.decode_steps += 1
-        self.slot_steps += len(live)
-        self.masked_slot_steps += len(dead)
-        self.pos[live] += 1
-        for i in live:
-            seq = self.active[i]
-            assert seq is not None and len(seq.out) < seq.max_new, \
-                f"slot {i}: token sampled for a freed/finished sequence"
-            tok = int(nxt[i])
-            seq.out.append(tok)
-            self.last_tok[i] = tok
-            m.tokens_out += 1
-            if len(seq.out) >= seq.max_new or self.pos[i] >= self.max_seq - 1:
-                self._finish(seq, done)
-        for i in dead:
-            # no token may be sampled for a freed slot
-            assert self.active[i] is None
-            self.last_tok[i] = 0
+        with trace_span("decode.launch"):
+            self.cache["pos"] = jnp.asarray(self.pos, jnp.int32)
+            if self.paged:
+                self.cache["tbl"] = jnp.asarray(self.tbl)
+            toks = jnp.asarray(self.last_tok[:, None])
+            nxt, self.cache = m.decode_rows(m.params, toks, self.cache)
+        with trace_span("decode.fetch"):
+            nxt = np.asarray(nxt)
+        with trace_span("decode.emit"):
+            self.decode_steps += 1
+            self.slot_steps += len(live)
+            self.masked_slot_steps += len(dead)
+            self.pos[live] += 1
+            for i in live:
+                seq = self.active[i]
+                assert seq is not None and len(seq.out) < seq.max_new, \
+                    f"slot {i}: token sampled for a freed/finished sequence"
+                tok = int(nxt[i])
+                seq.out.append(tok)
+                self.last_tok[i] = tok
+                m.tokens_out += 1
+                if len(seq.out) >= seq.max_new or \
+                        self.pos[i] >= self.max_seq - 1:
+                    self._finish(seq, done)
+            for i in dead:
+                # no token may be sampled for a freed slot
+                assert self.active[i] is None
+                self.last_tok[i] = 0
 
     def _decode_spec(self, live: List[int], done: List[SequenceState],
                      W: int):
@@ -841,67 +862,73 @@ class DecodeScheduler:
         dw = self.drafter
         dead = [i for i in range(self.slots) if self.active[i] is None]
         assert not set(dead) & set(live)
-        dw.catch_up(live)
-        props = dw.propose(live, W)          # (slots, W-1) draft tokens
-        toks = np.zeros((self.slots, W), np.int32)
-        for i in live:
-            toks[i, 0] = self.last_tok[i]    # pending token enters first
-            toks[i, 1:] = props[i]
-        self.cache["pos"] = jnp.asarray(self.pos, jnp.int32)
-        self.cache["tbl"] = jnp.asarray(self.tbl)
-        ver, self.cache = dw.rt.verify(m.params, jnp.asarray(toks),
-                                       self.cache)
-        ver = np.asarray(ver)                # (slots, W) greedy per position
-        self.decode_steps += 1
-        self.slot_steps += len(live)
-        self.masked_slot_steps += len(dead)
-        accs = []
-        for i in live:
-            seq = self.active[i]
-            assert seq is not None and len(seq.out) < seq.max_new, \
-                f"slot {i}: token sampled for a freed/finished sequence"
-            # proposals past the row's remaining token budget can never
-            # be emitted — exclude them from acceptance accounting (a
-            # row's final round would otherwise read as "rejections" and
-            # dilute the EWMA no matter how good the draft is)
-            rem = min(seq.max_new - len(seq.out),
-                      self.max_seq - 1 - int(self.pos[i]))
-            useful = min(W - 1, max(0, rem - 1))
-            # greedy acceptance: proposal d_{t+1} == target sample g_t
-            a = 0
-            while a < useful and props[i, a] == ver[i, a]:
-                a += 1
-            for t in range(a + 1):           # emit g_0..g_a, budget-capped
-                tok = int(ver[i, t])
-                seq.out.append(tok)
-                self.last_tok[i] = tok
-                m.tokens_out += 1
-                self.pos[i] += 1
-                self.spec_emitted += 1
+        with trace_span("spec.draft"):
+            dw.catch_up(live)
+            props = dw.propose(live, W)      # (slots, W-1) draft tokens
+        with trace_span("decode.launch"):
+            toks = np.zeros((self.slots, W), np.int32)
+            for i in live:
+                toks[i, 0] = self.last_tok[i]    # pending token enters first
+                toks[i, 1:] = props[i]
+            self.cache["pos"] = jnp.asarray(self.pos, jnp.int32)
+            self.cache["tbl"] = jnp.asarray(self.tbl)
+            ver, self.cache = dw.rt.verify(m.params, jnp.asarray(toks),
+                                           self.cache)
+        with trace_span("decode.fetch"):
+            ver = np.asarray(ver)            # (slots, W) greedy per position
+        with trace_span("decode.emit"):
+            self.decode_steps += 1
+            self.slot_steps += len(live)
+            self.masked_slot_steps += len(dead)
+            accs = []
+            for i in live:
+                seq = self.active[i]
+                assert seq is not None and len(seq.out) < seq.max_new, \
+                    f"slot {i}: token sampled for a freed/finished sequence"
+                # proposals past the row's remaining token budget can never
+                # be emitted — exclude them from acceptance accounting (a
+                # row's final round would otherwise read as "rejections" and
+                # dilute the EWMA no matter how good the draft is)
+                rem = min(seq.max_new - len(seq.out),
+                          self.max_seq - 1 - int(self.pos[i]))
+                useful = min(W - 1, max(0, rem - 1))
+                # greedy acceptance: proposal d_{t+1} == target sample g_t
+                a = 0
+                while a < useful and props[i, a] == ver[i, a]:
+                    a += 1
+                for t in range(a + 1):           # emit g_0..g_a, budget-capped
+                    tok = int(ver[i, t])
+                    seq.out.append(tok)
+                    self.last_tok[i] = tok
+                    m.tokens_out += 1
+                    self.pos[i] += 1
+                    self.spec_emitted += 1
+                    if len(seq.out) >= seq.max_new or \
+                            self.pos[i] >= self.max_seq - 1:
+                        break
+                if useful:
+                    acc = a / useful
+                    accs.append(acc)
+                    al = dw.spec.alpha
+                    dw.ewma[i] = (1.0 - al) * dw.ewma[i] + al * acc
+                dw.commit(i, W)
+                self.spec_offered += useful
+                self.spec_accepted += a
                 if len(seq.out) >= seq.max_new or \
                         self.pos[i] >= self.max_seq - 1:
-                    break
-            if useful:
-                acc = a / useful
-                accs.append(acc)
-                al = dw.spec.alpha
-                dw.ewma[i] = (1.0 - al) * dw.ewma[i] + al * acc
-            dw.commit(i, W)
-            self.spec_offered += useful
-            self.spec_accepted += a
-            if len(seq.out) >= seq.max_new or self.pos[i] >= self.max_seq - 1:
-                self._finish(seq, done)
-        self.spec_rounds += 1
-        if accs:
-            mean_acc = sum(accs) / len(accs)
-            self.spec_acceptance_ewma = mean_acc if self.spec_rounds == 1 \
-                else 0.8 * self.spec_acceptance_ewma + 0.2 * mean_acc
-            METRICS.observe("spec_accept_rate", mean_acc, arch=m.arch)
-        for i in dead:
-            # no token may be sampled for a freed slot (its verify lanes
-            # computed garbage that is asserted never to be read)
-            assert self.active[i] is None
-            self.last_tok[i] = 0
+                    self._finish(seq, done)
+            self.spec_rounds += 1
+            if accs:
+                mean_acc = sum(accs) / len(accs)
+                self.spec_acceptance_ewma = mean_acc \
+                    if self.spec_rounds == 1 \
+                    else 0.8 * self.spec_acceptance_ewma + 0.2 * mean_acc
+                METRICS.observe("spec_accept_rate", mean_acc, arch=m.arch)
+            for i in dead:
+                # no token may be sampled for a freed slot (its verify lanes
+                # computed garbage that is asserted never to be read)
+                assert self.active[i] is None
+                self.last_tok[i] = 0
 
     @property
     def spec_tokens_per_round(self) -> float:

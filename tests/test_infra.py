@@ -240,3 +240,20 @@ def test_metrics_scrape_format():
     assert 'vsr_requests_total{model="x"} 1.0' in s
     assert 'vsr_latency_ms_count{model="x"} 1' in s
     assert m.percentile("latency_ms", 50, model="x") == 12.5
+
+
+def test_metrics_histograms_are_bounded_with_exact_count_and_sum():
+    from repro.core.observability import HIST_RECENT, Metrics
+    m = Metrics()
+    n = 100_000
+    for i in range(n):
+        m.observe("latency_ms", float(i), stage="x")
+    h = m.hists['latency_ms{stage="x"}']
+    assert len(h.recent) == HIST_RECENT < n
+    assert h.count == n and h.sum == n * (n - 1) / 2
+    s = m.scrape()
+    assert f'vsr_latency_ms_count{{stage="x"}} {n}' in s
+    assert f'vsr_latency_ms_sum{{stage="x"}} {n * (n - 1) / 2:.6f}' in s
+    # percentiles read the most recent values
+    assert m.percentile("latency_ms", 0, stage="x") == n - HIST_RECENT
+    assert m.percentile("latency_ms", 100, stage="x") == n - 1

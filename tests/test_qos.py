@@ -321,6 +321,7 @@ def _preempt_roundtrip(fleet, arch):
             for p in victims]
     lane.step()                      # victims decode a couple of tokens
     lane.step()
+    begun = {s.rid: s.t_begin for s in sched.active if s is not None}
     hi = lane.submit("urgent vip request", max_new=2, priority=100,
                      slo="premium")
     finished = {}
@@ -331,6 +332,8 @@ def _preempt_roundtrip(fleet, arch):
     assert _counter("preemptions_total") == parks0 + 1
     victim = next(s for s in finished.values() if s.parks > 0)
     assert victim.priority == 0
+    # a resume keeps the time its first admission left the queue
+    assert 0 < victim.t_begin == begun[victim.rid] <= victim.t_first
     assert finished[hi].out          # VIP actually produced tokens
     for rid, want in zip(rids, ref):
         assert list(finished[rid].out) == want, \

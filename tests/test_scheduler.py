@@ -106,6 +106,39 @@ def test_transport_reports_per_request_service_time(fleet):
         assert o["usage"]["completion_tokens"] == 6
 
 
+@pytest.mark.parametrize("text", ["hello", "the same long prompt " * 12])
+def test_routed_request_splits_service_time_into_fleet_spans(fleet, text):
+    """A routed request's outcome trace carries the fleet's split of its
+    service time under ``upstream``: queue + prefill is the transport's
+    TTFT, and + decode its service time.  A long prompt is sent twice, so
+    the second admission reuses the first one's cached blocks."""
+    from repro.core.dsl import compile_source
+    from repro.core.router import SemanticRouter
+    from repro.core.types import Message, Request
+    cfg, _ = compile_source(
+        'GLOBAL { default_model: "m", model_profiles: '
+        '{ "m": { arch: "smollm-360m" } } }')
+    router = SemanticRouter(cfg, call_fn=fleet.call_fn({"m": ARCH}))
+    cached = fleet.lanes[ARCH].sched.cached_tokens
+    for _ in range(1 if text == "hello" else 2):
+        resp, out = router.route(Request(messages=[Message("user", text)]))
+    assert (fleet.lanes[ARCH].sched.cached_tokens > cached) == \
+        (text != "hello")
+    ms = {s["span"]: s["ms"] for s in out.trace}
+    u = resp.usage
+    assert ms["fleet:queue"] == u["vsr_queue_ms"] >= 0
+    assert ms["fleet:prefill"] == u["vsr_prefill_ms"] > 0
+    assert ms["fleet:queue"] + ms["fleet:prefill"] == \
+        pytest.approx(u["vsr_ttft_ms"], abs=0.01)
+    assert ms["fleet:queue"] + ms["fleet:prefill"] + ms["fleet:decode"] == \
+        pytest.approx(u["vsr_service_ms"], abs=0.01)
+    names = [s["span"] for s in out.trace]
+    i = names.index("upstream")
+    assert names[i + 1:i + 4] == ["fleet:queue", "fleet:prefill",
+                                  "fleet:decode"]
+    router.close()
+
+
 # ---------------------------------------------------------------------------
 # async front-end
 # ---------------------------------------------------------------------------
