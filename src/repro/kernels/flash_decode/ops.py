@@ -25,6 +25,11 @@ verify); a decode step calls it with W=1:
   ``(num_blocks, block_tokens, r)`` is one latent "head" shared by every
   query head.
 
+Both take the pools of every layer, stacked ``(L, num_blocks, ...)`` as
+the model's layer loop carries them, and the layer to read as a third
+scalar-prefetch operand that the KV index map returns as the leading
+block index: no layer's pool is sliced out before the call.
+
 Numerics: on CPU the paged kernels are TOKEN-EXACT against the XLA decode
 path (``layers.sdpa`` / ``layers.mla_attention``) — greedy sampling flips
 on last-ulp logit ties, so "close" is not enough there.  They therefore
@@ -102,6 +107,11 @@ def _vmem_limit(scratch, blocks) -> int:
     return min(_VMEM_CAP, _VMEM_DEFAULT + need)
 
 
+def _scalar(layer):
+    """The layer index as a scalar-prefetch operand."""
+    return jnp.reshape(jnp.asarray(layer, jnp.int32), (1,))
+
+
 def _live_block(lens_ref, b, ki, blk):
     """Clamp grid step ``ki`` to the row's last live block: repeated block
     indices make the Pallas pipeline skip the (re-)fetch, so padding-tail
@@ -137,12 +147,12 @@ def _softmax_pv(s_t, vals, out_dtype):
 # block-table GQA verify / decode kernel
 # ---------------------------------------------------------------------------
 
-def _paged_kernel(tbl_ref, lens_ref,        # scalar prefetch
+def _paged_kernel(tbl_ref, lens_ref, layer_ref,   # scalar prefetch
                   q_ref, k_ref, v_ref,      # VMEM blocks
                   o_ref,                    # output block
                   s_scr, v_scr,             # VMEM scratch
                   *, scale: float, blk: int, grid_k: int, hkv: int,
-                  w: int, gp: int):
+                  w: int, gp: int, hd: int):
     """One (row, page) grid step over every KV head of the page: the
     W*Gp q rows of a head share each streamed block, so speculation adds
     no KV traffic."""
@@ -162,13 +172,13 @@ def _paged_kernel(tbl_ref, lens_ref,        # scalar prefetch
         at = pl.ds(pl.multiple_of(ki * blk, blk), blk)
         for h in range(hkv):
             q = q_ref[0, h].astype(jnp.float32)         # (W*Gp, hd)
-            k = k_ref[0, h].astype(jnp.float32)         # (blk, hd)
+            k = k_ref[0, h, :, :hd].astype(jnp.float32)  # (blk, hd)
             s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                     preferred_element_type=jnp.float32)
             s = _masked_scores(s * scale, ki, kvl, blk=blk, w=w,
                                rows_per_pos=gp)
             s_scr[h, at, :] = s.T
-            v_scr[h, at, :] = v_ref[0, h]
+            v_scr[h, at, :] = v_ref[0, h, :, :hd]
 
     @pl.when(ki == grid_k - 1)
     def _finish():
@@ -177,19 +187,20 @@ def _paged_kernel(tbl_ref, lens_ref,        # scalar prefetch
 
 
 @jax.jit
-def paged_flash_verify(q, kpool, vpool, tbl, kv_len):
+def paged_flash_verify(q, kpool, vpool, tbl, kv_len, layer):
     """Attention for W query positions per row over the paged pool.
 
     q: (B, W, Hq, hd) — the pending token plus W-1 draft positions (W=1
     for a decode step), whose KV the caller has already written at
     ``kv_len - W .. kv_len - 1``;
-    kpool/vpool: (num_blocks, Hkv, block_tokens, hd); tbl: (B,
-    max_blocks) int32; kv_len: (B,) int32 TOTAL length including the W
-    new entries.  Returns (B, W, Hq, hd).  Position t masks to its own
-    frontier ``kv_len - W + t + 1``.
+    kpool/vpool: (L, num_blocks, Hkv, block_tokens, >= hd), the pools
+    of every layer (the minor dim may be padded past hd), of which the
+    int32 scalar ``layer`` is read; tbl: (B, max_blocks) int32; kv_len:
+    (B,) int32 TOTAL length including the W new entries.  Returns (B, W,
+    Hq, hd).  Position t masks to its own frontier ``kv_len - W + t + 1``.
     """
     B, W, Hq, hd = q.shape
-    Hkv, blk = kpool.shape[1], kpool.shape[2]
+    Hkv, blk, hp = kpool.shape[2:]
     max_blocks = tbl.shape[1]
     S = max_blocks * blk
     G = Hq // Hkv
@@ -203,24 +214,25 @@ def paged_flash_verify(q, kpool, vpool, tbl, kv_len):
 
     kernel = functools.partial(_paged_kernel, scale=1.0 / math.sqrt(hd),
                                blk=blk, grid_k=max_blocks, hkv=Hkv,
-                               w=W, gp=Gp)
+                               w=W, gp=Gp, hd=hd)
 
-    def q_map(b, ki, tbl_ref, lens_ref):
+    def q_map(b, ki, tbl_ref, lens_ref, layer_ref):
         return (b, 0, 0, 0)
 
-    def kv_map(b, ki, tbl_ref, lens_ref):
-        return (tbl_ref[b, _live_block(lens_ref, b, ki, blk)], 0, 0, 0)
+    def kv_map(b, ki, tbl_ref, lens_ref, layer_ref):
+        return (layer_ref[0], tbl_ref[b, _live_block(lens_ref, b, ki, blk)],
+                0, 0, 0)
 
     scratch = [((Hkv, S, R), jnp.float32), ((Hkv, S, hd), vpool.dtype)]
     blocks = [((Hkv, R, hd), q.dtype)] * 2 + \
-        [((Hkv, blk, hd), kpool.dtype)] * 2
+        [((Hkv, blk, hp), kpool.dtype)] * 2
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=3,
         grid=(B, max_blocks),
         in_specs=[
             pl.BlockSpec((1, Hkv, R, hd), q_map),
-            pl.BlockSpec((1, Hkv, blk, hd), kv_map),
-            pl.BlockSpec((1, Hkv, blk, hd), kv_map),
+            pl.BlockSpec((None, 1, Hkv, blk, hp), kv_map),
+            pl.BlockSpec((None, 1, Hkv, blk, hp), kv_map),
         ],
         out_specs=pl.BlockSpec((1, Hkv, R, hd), q_map),
         scratch_shapes=[pltpu.VMEM(s, d) for s, d in scratch],
@@ -232,7 +244,8 @@ def paged_flash_verify(q, kpool, vpool, tbl, kv_len):
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
             vmem_limit_bytes=_vmem_limit(scratch, blocks)),
-    )(tbl.astype(jnp.int32), kv_len.astype(jnp.int32), qf, kpool, vpool)
+    )(tbl.astype(jnp.int32), kv_len.astype(jnp.int32), _scalar(layer), qf,
+      kpool, vpool)
     out = out.reshape(B, Hkv, W, Gp, hd)[:, :, :, :G]
     return out.transpose(0, 2, 1, 3, 4).reshape(B, W, Hq, hd)
 
@@ -241,12 +254,12 @@ def paged_flash_verify(q, kpool, vpool, tbl, kv_len):
 # block-table MLA (absorbed-latent) verify / decode kernel
 # ---------------------------------------------------------------------------
 
-def _paged_mla_kernel(tbl_ref, lens_ref,
+def _paged_mla_kernel(tbl_ref, lens_ref, layer_ref,
                       ql_ref, qr_ref, ckv_ref, kr_ref,
                       o_ref,
                       s_scr, ckv_scr,
                       *, scale: float, blk: int, grid_k: int,
-                      w: int, hp: int):
+                      w: int, hp: int, r: int, rh: int):
     b = pl.program_id(0)
     ki = pl.program_id(1)
 
@@ -261,8 +274,8 @@ def _paged_mla_kernel(tbl_ref, lens_ref,
     def _stash():
         ql = ql_ref[0].astype(jnp.float32)          # (W*Hp, r)
         qr = qr_ref[0].astype(jnp.float32)          # (W*Hp, rh)
-        ckv = ckv_ref[0].astype(jnp.float32)        # (blk, r)
-        kr = kr_ref[0].astype(jnp.float32)          # (blk, rh)
+        ckv = ckv_ref[0, :, :r].astype(jnp.float32)     # (blk, r)
+        kr = kr_ref[0, :, :rh].astype(jnp.float32)      # (blk, rh)
         s = jax.lax.dot_general(ql, ckv, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32)
         s += jax.lax.dot_general(qr, kr, (((1,), (1,)), ((), ())),
@@ -270,7 +283,7 @@ def _paged_mla_kernel(tbl_ref, lens_ref,
         s = _masked_scores(s * scale, ki, kvl, blk=blk, w=w, rows_per_pos=hp)
         at = pl.ds(pl.multiple_of(ki * blk, blk), blk)
         s_scr[at, :] = s.T
-        ckv_scr[at, :] = ckv_ref[0]
+        ckv_scr[at, :] = ckv_ref[0, :, :r]
 
     @pl.when(ki == grid_k - 1)
     def _finish():
@@ -286,20 +299,23 @@ def _paged_mla_kernel(tbl_ref, lens_ref,
 
 @functools.partial(jax.jit, static_argnames=("scale",))
 def paged_flash_verify_mla(q_lat, q_rope, ckv_pool, krope_pool, tbl, kv_len,
-                           *, scale: float):
+                           layer, *, scale: float):
     """Absorbed-latent MLA attention for W positions per row over the
     paged compressed cache.
 
     q_lat: (B, W, H, r) latent queries (q_nope @ Wk_b); q_rope: (B, W, H,
-    rh); ckv_pool: (num_blocks, block_tokens, r); krope_pool: (num_blocks,
-    block_tokens, rh); tbl: (B, max_blocks) int32; kv_len: (B,) TOTAL
-    length including the W freshly written latents.  Returns the latent
-    context ctx = attn @ ckv, shape (B, W, H, r) — the caller applies
-    Wv_b / wo.  ``scale`` is 1/sqrt(nope_hd + rope_hd).
+    rh); ckv_pool: (L, num_blocks, block_tokens, >= r) and krope_pool:
+    (L, num_blocks, block_tokens, >= rh), every layer's (minor dims may
+    be padded), of which the int32 scalar ``layer`` is read; tbl: (B,
+    max_blocks) int32; kv_len: (B,) TOTAL length including the W freshly
+    written latents.  Returns the latent context ctx = attn @ ckv, shape
+    (B, W, H, r) — the caller applies Wv_b / wo.  ``scale`` is
+    1/sqrt(nope_hd + rope_hd).
     """
     B, W, H, r = q_lat.shape
     rh = q_rope.shape[-1]
-    blk = ckv_pool.shape[1]
+    blk, rp = ckv_pool.shape[2:]
+    rhp = krope_pool.shape[-1]
     max_blocks = tbl.shape[1]
     S = max_blocks * blk
     Hp = max(8, H)
@@ -313,26 +329,27 @@ def paged_flash_verify_mla(q_lat, q_rope, ckv_pool, krope_pool, tbl, kv_len,
     qr = qr.reshape(B, R, rh)
 
     kernel = functools.partial(_paged_mla_kernel, scale=scale, blk=blk,
-                               grid_k=max_blocks, w=W, hp=Hp)
+                               grid_k=max_blocks, w=W, hp=Hp, r=r, rh=rh)
 
-    def q_map(b, ki, tbl_ref, lens_ref):
+    def q_map(b, ki, tbl_ref, lens_ref, layer_ref):
         return (b, 0, 0)
 
-    def kv_map(b, ki, tbl_ref, lens_ref):
-        return (tbl_ref[b, _live_block(lens_ref, b, ki, blk)], 0, 0)
+    def kv_map(b, ki, tbl_ref, lens_ref, layer_ref):
+        return (layer_ref[0], tbl_ref[b, _live_block(lens_ref, b, ki, blk)],
+                0, 0)
 
     scratch = [((S, R), jnp.float32), ((S, r), ckv_pool.dtype)]
     blocks = [((R, r), q_lat.dtype)] * 2 + [((R, rh), q_rope.dtype),
-                                            ((blk, r), ckv_pool.dtype),
-                                            ((blk, rh), krope_pool.dtype)]
+                                            ((blk, rp), ckv_pool.dtype),
+                                            ((blk, rhp), krope_pool.dtype)]
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=3,
         grid=(B, max_blocks),
         in_specs=[
             pl.BlockSpec((1, R, r), q_map),
             pl.BlockSpec((1, R, rh), q_map),
-            pl.BlockSpec((1, blk, r), kv_map),
-            pl.BlockSpec((1, blk, rh), kv_map),
+            pl.BlockSpec((None, 1, blk, rp), kv_map),
+            pl.BlockSpec((None, 1, blk, rhp), kv_map),
         ],
         out_specs=pl.BlockSpec((1, R, r), q_map),
         scratch_shapes=[pltpu.VMEM(s, d) for s, d in scratch],
@@ -344,6 +361,6 @@ def paged_flash_verify_mla(q_lat, q_rope, ckv_pool, krope_pool, tbl, kv_len,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
             vmem_limit_bytes=_vmem_limit(scratch, blocks)),
-    )(tbl.astype(jnp.int32), kv_len.astype(jnp.int32), ql, qr,
-      ckv_pool, krope_pool)
+    )(tbl.astype(jnp.int32), kv_len.astype(jnp.int32), _scalar(layer), ql,
+      qr, ckv_pool, krope_pool)
     return ctx.reshape(B, W, Hp, r)[:, :, :H, :]

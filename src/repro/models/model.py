@@ -145,20 +145,30 @@ def paged_supported(cfg: ModelConfig) -> bool:
                 for g in cfg.groups for s in g.period))
 
 
-def _block_paged_cache(cfg: ModelConfig, spec: BlockSpec, num_blocks: int,
-                       block_tokens: int, dtype):
+def _lanes(width: int) -> int:
+    """A pool's minor dim, padded to whole 128-lane tiles.  A TPU lays an
+    array whose minor dim is narrower out with another dim minor (here
+    the block index), which pages cannot be read from: the paged kernels
+    take row-major operands, so every step would relayout every pool.
+    Readers slice the real width back out."""
+    return -(-width // 128) * 128
+
+
+def _block_paged_cache(cfg: ModelConfig, spec: BlockSpec, repeats: int,
+                       num_blocks: int, block_tokens: int, dtype):
     # the block-token axis is second-minor in every pool: GQA pools are
     # head-major so one page of one KV head is a contiguous (blk, hd)
     # tile (the paged Pallas kernels DMA whole pages of every head)
+    lead = (repeats, num_blocks)
     if spec.mixer == "attn":
-        kv = (num_blocks, cfg.n_kv_heads, block_tokens, cfg.head_dim)
+        kv = lead + (cfg.n_kv_heads, block_tokens, _lanes(cfg.head_dim))
         return {"k": jnp.zeros(kv, dtype), "v": jnp.zeros(kv, dtype)}
     if spec.mixer == "mla":
         return {
-            "ckv": jnp.zeros((num_blocks, block_tokens, cfg.kv_lora_rank),
+            "ckv": jnp.zeros(lead + (block_tokens, _lanes(cfg.kv_lora_rank)),
                              dtype),
-            "krope": jnp.zeros((num_blocks, block_tokens, cfg.rope_head_dim),
-                               dtype),
+            "krope": jnp.zeros(
+                lead + (block_tokens, _lanes(cfg.rope_head_dim)), dtype),
         }
     raise ValueError(f"paged cache unsupported for mixer {spec.mixer!r}")
 
@@ -166,7 +176,8 @@ def _block_paged_cache(cfg: ModelConfig, spec: BlockSpec, num_blocks: int,
 def init_paged_cache(cfg: ModelConfig, slots: int, max_seq: int,
                      num_blocks: int, block_tokens: int) -> dict:
     """Paged serving cache: KV leaves are physical block pools
-    ``(repeats, num_blocks, [Hkv,] block_tokens, d)`` shared by every slot;
+    ``(repeats, num_blocks, [Hkv,] block_tokens, d)`` shared by every slot
+    (``d`` padded to whole 128-lane tiles, ``_lanes``);
     ``cache["tbl"]`` (slots, max_seq // block_tokens) maps each slot's
     logical blocks to physical ones (0 = the reserved trash block).  The
     per-slot table width equals the contiguous ``max_seq``, so a gathered
@@ -177,18 +188,11 @@ def init_paged_cache(cfg: ModelConfig, slots: int, max_seq: int,
     dtype = _dtype(cfg)
     cache = {"pos": jnp.zeros((slots,), jnp.int32),
              "tbl": jnp.zeros((slots, max_seq // block_tokens), jnp.int32)}
-
-    def stack(tree, n):
-        return jax.tree.map(
-            lambda x: jnp.broadcast_to(x, (n,) + x.shape).copy(), tree)
-
     for i, g in enumerate(cfg.groups):
-        gc = {}
-        for j, spec in enumerate(g.period):
-            bc = _block_paged_cache(cfg, spec, num_blocks, block_tokens,
-                                    dtype)
-            gc[f"b{j}"] = stack(bc, g.repeats)
-        cache[f"g{i}"] = gc
+        cache[f"g{i}"] = {
+            f"b{j}": _block_paged_cache(cfg, spec, g.repeats, num_blocks,
+                                        block_tokens, dtype)
+            for j, spec in enumerate(g.period)}
     return cache
 
 
@@ -209,30 +213,52 @@ def _cache_write(buf: Array, val: Array, pos) -> Array:
         lambda b, v, p: jax.lax.dynamic_update_slice(
             b, v, (p,) + (0,) * (b.ndim - 1)))(buf, val, pos)
 
-def _paged_write(pool: Array, val: Array, tbl: Array, pos) -> Array:
-    """Scatter ``val`` (B, T, [H,] d) into the physical block pool
-    ``(num_blocks, [H,] block_tokens, d)`` at each row's absolute
-    positions ``pos .. pos+T`` through its block-table row ``tbl`` (B,
-    max_blocks).  Positions past the table (or rows whose table maps to
-    0) land in the reserved trash block — freed slots and pad tails write
-    garbage somewhere harmless instead of into live rows."""
+def _paged_write(pool: Array, val: Array, tbl: Array, pos, layer) -> Array:
+    """Write ``val`` (B, T, [H,] d) into layer ``layer`` of the block
+    pools ``(L, num_blocks, [H,] block_tokens, d)`` at each row's
+    absolute positions ``pos .. pos+T`` through its block-table row
+    ``tbl`` (B, max_blocks).  Positions past the table (or rows whose
+    table maps to 0) land in the reserved trash block — freed slots and
+    pad tails write garbage somewhere harmless instead of into live rows.
+
+    Whole blocks are stored: the blocks a row's run touches are read,
+    its tokens merged in, and one scatter puts them back.  A block's
+    window spans the pool's trailing dims, so the scatter updates the
+    stacked pools in place in their own layout (a token-sized window
+    makes the compiler relayout the pools around it)."""
     blk = pool.shape[-2]
     B, T = val.shape[:2]
+    mb = tbl.shape[1]
+    tail = (1,) * (val.ndim - 2)
+    val = jnp.pad(val.astype(pool.dtype), [(0, 0)] * (val.ndim - 1)
+                  + [(0, pool.shape[-1] - val.shape[-1])])
     pos = jnp.broadcast_to(jnp.asarray(pos, jnp.int32), (B,))
-    positions = pos[:, None] + jnp.arange(T, dtype=jnp.int32)[None, :]
-    bi = positions // blk
-    phys = jnp.take_along_axis(tbl, jnp.clip(bi, 0, tbl.shape[1] - 1), axis=1)
-    phys = jnp.where(bi >= tbl.shape[1], 0, phys)
-    heads = (slice(None),) * (pool.ndim - 3)
-    return pool.at[(phys.reshape(-1),) + heads + (
-        (positions % blk).reshape(-1),)].set(
-        val.astype(pool.dtype).reshape((B * T,) + val.shape[2:]))
+    off = pos % blk
+    nb = (T + blk - 2) // blk + 1          # blocks a T-token run can touch
+    lb = pos[:, None] // blk + jnp.arange(nb, dtype=jnp.int32)[None, :]
+    phys = jnp.take_along_axis(tbl, jnp.clip(lb, 0, mb - 1), axis=1)
+    cols = jnp.arange(nb * blk, dtype=jnp.int32)[None, :]
+    live = (cols >= off[:, None]) & (cols < off[:, None] + T)
+    new = jnp.take_along_axis(
+        val, jnp.clip(cols - off[:, None], 0, T - 1).reshape((B, -1) + tail),
+        axis=1)
+    old = jnp.moveaxis(pool[layer, phys], -2, 2).reshape(new.shape)
+    blocks = jnp.where(live.reshape(live.shape + tail), new, old)
+    blocks = jnp.moveaxis(blocks.reshape((B * nb, blk) + val.shape[2:]), 1,
+                          -2)
+    # a block the run leaves untouched, or one past the table, is not
+    # stored back: it goes to the trash block
+    touched = live.reshape(B, nb, blk).any(-1) & (lb < mb)
+    phys = jnp.where(touched, phys, 0).reshape(-1)
+    return pool.at[layer, phys].set(blocks, mode="promise_in_bounds")
 
 
-def _paged_view(pool: Array, tbl: Array) -> Array:
+def _paged_view(pool: Array, tbl: Array, layer, width: int) -> Array:
     """Gather each row's contiguous-layout KV view (B, max_blocks*blk,
-    [H,] d) from the physical pool through its block table."""
-    g = jnp.moveaxis(pool[tbl], -2, 2)          # (B, mb, blk, [H,] d)
+    [H,] width) of layer ``layer`` straight from the stacked pools
+    through its block table (one gather: no layer slice is
+    materialized); ``width`` is the real minor dim (``_lanes``)."""
+    g = jnp.moveaxis(pool[layer, tbl][..., :width], -2, 2)
     return g.reshape((tbl.shape[0], -1) + g.shape[3:])
 
 
@@ -253,12 +279,13 @@ def _sdpa_impl(cfg, q, k, v, **kw):
 
 
 def _self_attn(cfg, p, h, rope, mode, bcache, pos, bidir=False, tbl=None,
-               paged_fresh=False):
+               layer=None, paged_fresh=False):
     """Self-attention in all three modes.  Returns (out, new_cache).
 
     ``tbl`` (B, max_blocks) switches the cache to the paged layout: KV
-    leaves are physical block pools and reads/writes go through each
-    row's block table.  ``paged_fresh`` marks a from-scratch paged
+    leaves are the physical block pools of every layer of the group,
+    ``layer`` says which is this block's, and reads/writes go through
+    each row's block table.  ``paged_fresh`` marks a from-scratch paged
     prefill (no cached prefix): attention then runs on the local K/V
     exactly like the contiguous path — bit-identical first token — and
     only the WRITES go through the table.  A paged suffix prefill
@@ -282,14 +309,15 @@ def _self_attn(cfg, p, h, rope, mode, bcache, pos, bidir=False, tbl=None,
         return h + L.attn_out(p["attn"], out), new_cache
     if mode == "prefill":
         if tbl is not None:
-            kpool = _paged_write(bcache["k"], k, tbl, pos)
-            vpool = _paged_write(bcache["v"], v, tbl, pos)
+            kpool = _paged_write(bcache["k"], k, tbl, pos, layer)
+            vpool = _paged_write(bcache["v"], v, tbl, pos, layer)
             if paged_fresh:
                 out = _sdpa_impl(cfg, q, k, v, causal=causal,
                                  sliding_window=cfg.sliding_window)
             else:
-                out = L.sdpa(q, _paged_view(kpool, tbl),
-                             _paged_view(vpool, tbl), causal=causal,
+                hd = cfg.head_dim
+                out = L.sdpa(q, _paged_view(kpool, tbl, layer, hd),
+                             _paged_view(vpool, tbl, layer, hd), causal=causal,
                              sliding_window=cfg.sliding_window, q_offset=pos)
             return h + L.attn_out(p["attn"], out), {"k": kpool, "v": vpool}
         out = _sdpa_impl(cfg, q, k, v, causal=causal,
@@ -308,15 +336,16 @@ def _self_attn(cfg, p, h, rope, mode, bcache, pos, bidir=False, tbl=None,
         # sees, so every verify position is bitwise-identical to the
         # non-speculative decode path's.
         W = q.shape[1]
-        kpool = _paged_write(bcache["k"], k, tbl, pos)
-        vpool = _paged_write(bcache["v"], v, tbl, pos)
+        kpool = _paged_write(bcache["k"], k, tbl, pos, layer)
+        vpool = _paged_write(bcache["v"], v, tbl, pos, layer)
         if cfg.decode_impl == "flash_paged":
             from repro.kernels.flash_decode.ops import paged_flash_verify
-            out = paged_flash_verify(q, kpool, vpool, tbl, pos + W)
+            out = paged_flash_verify(q, kpool, vpool, tbl, pos + W, layer)
         else:
-            out = L.sdpa(q, _paged_view(kpool, tbl), _paged_view(vpool, tbl),
-                         causal=True, q_offset=pos, kv_len=pos + W,
-                         sliding_window=0)
+            hd = cfg.head_dim
+            out = L.sdpa(q, _paged_view(kpool, tbl, layer, hd),
+                         _paged_view(vpool, tbl, layer, hd), causal=True,
+                         q_offset=pos, kv_len=pos + W, sliding_window=0)
         return h + L.attn_out(p["attn"], out), {"k": kpool, "v": vpool}
     # contiguous decode (pos: scalar, or (B,) per-row positions)
     if cfg.decode_impl == "shardmap" and jnp.ndim(pos) == 0:
@@ -353,7 +382,7 @@ def _cross_attn(cfg, p, h, cross_ctx, mode, bcache):
     return h + L.attn_out(p["attn"], out), new_cache
 
 
-def _mla_attn(cfg, p, h, rope, mode, bcache, pos, tbl=None,
+def _mla_attn(cfg, p, h, rope, mode, bcache, pos, tbl=None, layer=None,
               paged_fresh=False):
     x = L.rms_norm(h, p["norm1"], cfg.norm_eps)
     mp = p["mla"]
@@ -364,8 +393,8 @@ def _mla_attn(cfg, p, h, rope, mode, bcache, pos, tbl=None,
         return h + out, None
     if mode == "prefill":
         if tbl is not None:
-            ckv_p = _paged_write(bcache["ckv"], c_kv, tbl, pos)
-            krope_p = _paged_write(bcache["krope"], k_rope, tbl, pos)
+            ckv_p = _paged_write(bcache["ckv"], c_kv, tbl, pos, layer)
+            krope_p = _paged_write(bcache["krope"], k_rope, tbl, pos, layer)
             if paged_fresh:
                 out = _mla_naive(cfg, mp, q_nope, q_rope, c_kv, k_rope)
             else:
@@ -374,9 +403,11 @@ def _mla_attn(cfg, p, h, rope, mode, bcache, pos, tbl=None,
                 # matmul ((q@wk_b)·ckv vs q·(ckv@wk_b)), and that last-ulp
                 # logit difference flips greedy argmax on near-ties —
                 # paged suffix tokens must equal the contiguous path's
-                out = _mla_naive(cfg, mp, q_nope, q_rope,
-                                 _paged_view(ckv_p, tbl),
-                                 _paged_view(krope_p, tbl), q_offset=pos)
+                out = _mla_naive(
+                    cfg, mp, q_nope, q_rope,
+                    _paged_view(ckv_p, tbl, layer, cfg.kv_lora_rank),
+                    _paged_view(krope_p, tbl, layer, cfg.rope_head_dim),
+                    q_offset=pos)
             return h + out, {"ckv": ckv_p, "krope": krope_p}
         new_cache = {
             "ckv": jax.lax.dynamic_update_slice(
@@ -395,22 +426,23 @@ def _mla_attn(cfg, p, h, rope, mode, bcache, pos, tbl=None,
     # kv_len = pos + t + 1.
     if tbl is not None:
         W = q_nope.shape[1]
-        ckv_p = _paged_write(bcache["ckv"], c_kv, tbl, pos)
-        krope_p = _paged_write(bcache["krope"], k_rope, tbl, pos)
+        ckv_p = _paged_write(bcache["ckv"], c_kv, tbl, pos, layer)
+        krope_p = _paged_write(bcache["krope"], k_rope, tbl, pos, layer)
         if cfg.decode_impl == "flash_paged":
             from repro.kernels.flash_decode.ops import paged_flash_verify_mla
             B, Sq, H, _ = q_nope.shape
             q_lat = jnp.einsum("bqhn,hrn->bqhr", q_nope, mp["wk_b"])
             ctx = paged_flash_verify_mla(
-                q_lat, q_rope, ckv_p, krope_p, tbl, pos + W,
+                q_lat, q_rope, ckv_p, krope_p, tbl, pos + W, layer,
                 scale=1.0 / math.sqrt(cfg.nope_head_dim + cfg.rope_head_dim))
             out = jnp.einsum("bqhr,hrv->bqhv", ctx, mp["wv_b"])
             out = out.reshape(B, Sq, H * cfg.v_head_dim) @ mp["wo"]
         else:
-            out = L.mla_attention(mp, cfg, q_nope, q_rope,
-                                  _paged_view(ckv_p, tbl),
-                                  _paged_view(krope_p, tbl),
-                                  causal=True, q_offset=pos, kv_len=pos + W)
+            out = L.mla_attention(
+                mp, cfg, q_nope, q_rope,
+                _paged_view(ckv_p, tbl, layer, cfg.kv_lora_rank),
+                _paged_view(krope_p, tbl, layer, cfg.rope_head_dim),
+                causal=True, q_offset=pos, kv_len=pos + W)
         return h + out, {"ckv": ckv_p, "krope": krope_p}
     # contiguous decode (pos: scalar, or (B,) per-row positions)
     if cfg.decode_impl == "shardmap" and jnp.ndim(pos) == 0:
@@ -452,12 +484,13 @@ def _mla_naive(cfg, mp, q_nope, q_rope, c_kv, k_rope, q_offset=None):
 
 def _apply_block(cfg: ModelConfig, spec: BlockSpec, p: dict, h: Array, *,
                  rope, cross_ctx, mode: str, bcache, pos, moe_impl: str,
-                 tbl=None, paged_fresh=False):
+                 tbl=None, layer=None, paged_fresh=False):
     new_cache, aux = bcache, (jnp.zeros((), jnp.float32),) * 2
 
     if spec.mixer == "attn":
         h, new_cache = _self_attn(cfg, p, h, rope, mode, bcache, pos,
-                                  tbl=tbl, paged_fresh=paged_fresh)
+                                  tbl=tbl, layer=layer,
+                                  paged_fresh=paged_fresh)
     elif spec.mixer == "bidir_attn":
         h, new_cache = _self_attn(cfg, p, h, rope, mode, bcache, pos,
                                   bidir=True)
@@ -465,7 +498,8 @@ def _apply_block(cfg: ModelConfig, spec: BlockSpec, p: dict, h: Array, *,
         h, new_cache = _cross_attn(cfg, p, h, cross_ctx, mode, bcache)
     elif spec.mixer == "mla":
         h, new_cache = _mla_attn(cfg, p, h, rope, mode, bcache, pos,
-                                 tbl=tbl, paged_fresh=paged_fresh)
+                                 tbl=tbl, layer=layer,
+                                 paged_fresh=paged_fresh)
     elif spec.mixer in ("mamba", "mlstm", "slstm"):
         x = L.rms_norm(h, p["norm1"], cfg.norm_eps)
         fwd = {"mamba": (S.mamba_forward, S.mamba_step),
@@ -508,32 +542,40 @@ def _run_groups(cfg: ModelConfig, params: dict, h: Array, groups, prefix, *,
     for i, g in enumerate(groups):
         gp = params[f"{prefix}{i}"]
         gc = cache.get(f"g{i}") if cache is not None else None
+        # paged block pools ride in the carry, indexed by the scanned
+        # layer number, so each layer updates them in place; as scan
+        # xs/ys every step would restack a copy of every layer's pool
+        paged = gc is not None and tbl is not None
 
-        def body(carry, xs, _g=g):
-            h, lb, z = carry
-            if gc is not None:
-                bp, bc = xs
-            else:
-                bp, bc = xs, None
+        def body(carry, xs, _g=g, _paged=paged):
+            h, lb, z, pools = carry
+            bp, per_layer = xs
+            bc, layer = (pools, per_layer) if _paged else (per_layer, None)
             out_cache = {}
             for j, spec in enumerate(_g.period):
                 bcj = bc[f"b{j}"] if bc is not None else None
                 h, ncj, (alb, az) = _apply_block(
                     cfg, spec, bp[f"b{j}"], h, rope=rope, cross_ctx=cross_ctx,
                     mode=mode, bcache=bcj, pos=pos, moe_impl=moe_impl,
-                    tbl=tbl, paged_fresh=paged_fresh)
+                    tbl=tbl, layer=layer, paged_fresh=paged_fresh)
                 lb, z = lb + alb, z + az
                 out_cache[f"b{j}"] = ncj
-            return (h, lb, z), out_cache
+            if _paged:
+                return (h, lb, z, out_cache), None
+            return (h, lb, z, None), out_cache
 
         if remat and mode == "full":
             body = jax.checkpoint(body)
 
-        xs = (gp, gc) if gc is not None else gp
-        (h, lb_total, z_total), ys = jax.lax.scan(
-            body, (h, lb_total, z_total), xs)
-        if gc is not None:
-            new_cache[f"g{i}"] = ys
+        if paged:
+            xs = (gp, jnp.arange(g.repeats, dtype=jnp.int32))
+            (h, lb_total, z_total, new_cache[f"g{i}"]), _ = jax.lax.scan(
+                body, (h, lb_total, z_total, gc), xs)
+        else:
+            (h, lb_total, z_total, _), ys = jax.lax.scan(
+                body, (h, lb_total, z_total, None), (gp, gc))
+            if gc is not None:
+                new_cache[f"g{i}"] = ys
     return h, new_cache, {"moe_lb": lb_total, "moe_z": z_total}
 
 

@@ -89,27 +89,27 @@ def test_decision_gate_is_lint_clean():
 
 
 def test_paged_flash_decode_kernels_are_lint_clean():
-    """Decode is the verify kernels at W=1."""
+    """Decode is the verify kernels at W=1, reading layer 1 of two."""
     from repro.kernels.flash_decode.ops import (paged_flash_verify,
                                                 paged_flash_verify_mla)
     B, nb, max_blocks, blk, Hq, Hkv, hd = 3, 10, 4, 16, 8, 2, 64
     S = max_blocks * blk
     q = jnp.zeros((B, 1, Hq, hd), jnp.float32)
-    kpool = jnp.zeros((nb, Hkv, blk, hd), jnp.float32)
-    vpool = jnp.zeros((nb, Hkv, blk, hd), jnp.float32)
+    kpool = jnp.zeros((2, nb, Hkv, blk, hd), jnp.float32)
+    vpool = jnp.zeros((2, nb, Hkv, blk, hd), jnp.float32)
     tbl = jnp.zeros((B, max_blocks), jnp.int32)
     kv_len = jnp.zeros((B,), jnp.int32)
-    findings = lint_fn(paged_flash_verify, q, kpool, vpool, tbl, kv_len,
+    findings = lint_fn(paged_flash_verify, q, kpool, vpool, tbl, kv_len, 1,
                        banned_leading_shapes=[(B, S), (B * 2, S)])
     assert findings == [], [str(f) for f in findings]
 
     r, rh = 64, 32
     ql = jnp.zeros((B, 1, Hq, r), jnp.float32)
     qr = jnp.zeros((B, 1, Hq, rh), jnp.float32)
-    ckv = jnp.zeros((nb, blk, r), jnp.float32)
-    kr = jnp.zeros((nb, blk, rh), jnp.float32)
+    ckv = jnp.zeros((2, nb, blk, r), jnp.float32)
+    kr = jnp.zeros((2, nb, blk, rh), jnp.float32)
     findings = lint_fn(
-        paged_flash_verify_mla, ql, qr, ckv, kr, tbl, kv_len,
+        paged_flash_verify_mla, ql, qr, ckv, kr, tbl, kv_len, 1,
         banned_leading_shapes=[(B, S), (B * 2, S)],
         scale=1.0 / np.sqrt(96.0))
     assert findings == [], [str(f) for f in findings]
@@ -124,21 +124,21 @@ def test_paged_flash_verify_kernels_are_lint_clean():
     B, nb, max_blocks, blk, Hq, Hkv, hd, W = 3, 10, 4, 16, 8, 2, 64, 5
     S = max_blocks * blk
     q = jnp.zeros((B, W, Hq, hd), jnp.float32)
-    kpool = jnp.zeros((nb, Hkv, blk, hd), jnp.float32)
-    vpool = jnp.zeros((nb, Hkv, blk, hd), jnp.float32)
+    kpool = jnp.zeros((2, nb, Hkv, blk, hd), jnp.float32)
+    vpool = jnp.zeros((2, nb, Hkv, blk, hd), jnp.float32)
     tbl = jnp.zeros((B, max_blocks), jnp.int32)
     kv_len = jnp.zeros((B,), jnp.int32)
-    findings = lint_fn(paged_flash_verify, q, kpool, vpool, tbl, kv_len,
+    findings = lint_fn(paged_flash_verify, q, kpool, vpool, tbl, kv_len, 1,
                        banned_leading_shapes=[(B, S), (B * 2, S)])
     assert findings == [], [str(f) for f in findings]
 
     r, rh = 64, 32
     ql = jnp.zeros((B, W, Hq, r), jnp.float32)
     qr = jnp.zeros((B, W, Hq, rh), jnp.float32)
-    ckv = jnp.zeros((nb, blk, r), jnp.float32)
-    kr = jnp.zeros((nb, blk, rh), jnp.float32)
+    ckv = jnp.zeros((2, nb, blk, r), jnp.float32)
+    kr = jnp.zeros((2, nb, blk, rh), jnp.float32)
     findings = lint_fn(
-        paged_flash_verify_mla, ql, qr, ckv, kr, tbl, kv_len,
+        paged_flash_verify_mla, ql, qr, ckv, kr, tbl, kv_len, 1,
         banned_leading_shapes=[(B, S), (B * 2, S)],
         scale=1.0 / np.sqrt(96.0))
     assert findings == [], [str(f) for f in findings]
@@ -199,36 +199,37 @@ def test_decision_gate_no_recompile_across_warm_buckets():
 
 def test_verify_kernels_no_recompile_across_warm_width_buckets():
     """Replayed verify widths (the adaptive scheduler only issues
-    W in {1, k+1}) never miss the jit cache once warmed."""
+    W in {1, k+1}) never miss the jit cache once warmed, whichever layer
+    of the stacked pools a call reads."""
     from repro.kernels.flash_decode.ops import (paged_flash_verify,
                                                 paged_flash_verify_mla)
     B, nb, max_blocks, blk, Hq, Hkv, hd = 2, 6, 2, 16, 8, 2, 64
-    kpool = jnp.zeros((nb, Hkv, blk, hd), jnp.float32)
-    vpool = jnp.zeros((nb, Hkv, blk, hd), jnp.float32)
-    ckv = jnp.zeros((nb, blk, 64), jnp.float32)
-    kr = jnp.zeros((nb, blk, 32), jnp.float32)
+    kpool = jnp.zeros((2, nb, Hkv, blk, hd), jnp.float32)
+    vpool = jnp.zeros((2, nb, Hkv, blk, hd), jnp.float32)
+    ckv = jnp.zeros((2, nb, blk, 64), jnp.float32)
+    kr = jnp.zeros((2, nb, blk, 32), jnp.float32)
     tbl = jnp.zeros((B, max_blocks), jnp.int32)
     kv_len = jnp.full((B,), 8, jnp.int32)
 
-    def gqa(W):
+    def gqa(W, layer=0):
         return paged_flash_verify(jnp.zeros((B, W, Hq, hd), jnp.float32),
-                                  kpool, vpool, tbl, kv_len)
+                                  kpool, vpool, tbl, kv_len, layer)
 
-    def mla(W):
+    def mla(W, layer=0):
         return paged_flash_verify_mla(
             jnp.zeros((B, W, Hq, 64), jnp.float32),
             jnp.zeros((B, W, Hq, 32), jnp.float32),
-            ckv, kr, tbl, kv_len, scale=0.1)
+            ckv, kr, tbl, kv_len, layer, scale=0.1)
 
     for W in (1, 5):                     # warm both width buckets
         gqa(W)
         mla(W)
     guard = RecompileGuard({"verify": paged_flash_verify,
                             "verify_mla": paged_flash_verify_mla})
-    for _ in range(3):
+    for layer in (0, 1, 1):
         for W in (1, 5):
-            gqa(W)
-            mla(W)
+            gqa(W, layer)
+            mla(W, layer)
     guard.assert_no_recompiles()
 
 

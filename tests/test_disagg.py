@@ -66,7 +66,7 @@ def _rand_case(rnd, *, B, nb, max_blocks, blk, zero_rows=True):
     return jnp.asarray(tbl), jnp.asarray(kv_len)
 
 
-def test_paged_flash_decode_kernel_sweep(rng):
+def test_paged_flash_decode_kernel_sweep(rng, stacked):
     """Random tables/lengths vs the gather oracle.  Zero-length lanes are
     checked against the kernel's contract (exact zeros) separately — the
     oracle's all-masked softmax degenerates to a uniform average."""
@@ -80,8 +80,9 @@ def test_paged_flash_decode_kernel_sweep(rng):
         tbl, kv_len = _rand_case(rnd, B=B, nb=nb, max_blocks=max_blocks,
                                  blk=blk)
         q = jnp.asarray(rng.standard_normal((B, Hq, hd)), jnp.float32)
-        out = np.asarray(paged_flash_verify(q[:, None], kpool, vpool, tbl,
-                                            kv_len))[:, 0]
+        out = np.asarray(paged_flash_verify(
+            q[:, None], stacked(kpool), stacked(vpool), tbl, kv_len,
+            stacked.layer))[:, 0]
         ref = np.asarray(paged_decode_reference(q, kpool, vpool, tbl,
                                                 kv_len))
         lens = np.asarray(kv_len)
@@ -91,7 +92,7 @@ def test_paged_flash_decode_kernel_sweep(rng):
         assert (out[~live] == 0.0).all(), f"seed={seed}: kv_len==0 lanes"
 
 
-def test_paged_flash_decode_mla_kernel_sweep(rng):
+def test_paged_flash_decode_mla_kernel_sweep(rng, stacked):
     from repro.kernels.flash_decode import (paged_flash_verify_mla,
                                             paged_mla_decode_reference)
     B, nb, max_blocks, blk, H, r, rh = 4, 10, 4, 16, 8, 64, 32
@@ -105,8 +106,8 @@ def test_paged_flash_decode_mla_kernel_sweep(rng):
         ql = jnp.asarray(rng.standard_normal((B, H, r)), jnp.float32)
         qr = jnp.asarray(rng.standard_normal((B, H, rh)), jnp.float32)
         out = np.asarray(paged_flash_verify_mla(
-            ql[:, None], qr[:, None], ckv, kr, tbl, kv_len,
-            scale=scale))[:, 0]
+            ql[:, None], qr[:, None], stacked(ckv), stacked(kr), tbl, kv_len,
+            stacked.layer, scale=scale))[:, 0]
         ref = np.asarray(paged_mla_decode_reference(ql, qr, ckv, kr, tbl,
                                                     kv_len, scale=scale))
         lens = np.asarray(kv_len)
@@ -138,7 +139,7 @@ def _xla_mla(ql, qr, ckv, kr, tbl, kv_len, *, scale):
                                 q_offset=kv_len - ql.shape[1], kv_len=kv_len)
 
 
-def test_paged_flash_decode_compiled_matches_interpret(rng, tpu):
+def test_paged_flash_decode_compiled_matches_interpret(rng, tpu, stacked):
     """Both paged kernels at W=1 (decode) and W=3 (verify) on bf16
     inputs, the serving dtype.
 
@@ -152,7 +153,8 @@ def test_paged_flash_decode_compiled_matches_interpret(rng, tpu):
     XLA:TPU with XLA:CPU arithmetic (exp, reduction order) on top: the
     same 80 inputs differed by at most 2^-7, hence ``CROSS_PLATFORM_ATOL``.
     f32 inputs would measure the chip's default matmul precision instead
-    (bf16 operands; 8.4e-3)."""
+    (bf16 operands; 8.4e-3).  The kernels read one layer of stacked
+    pools; the XLA path reads that layer's pools."""
     from repro.kernels.flash_decode import (paged_flash_verify,
                                             paged_flash_verify_mla)
     B, nb, max_blocks, blk, Hq, Hkv, hd = 3, 8, 4, 16, 8, 2, 64
@@ -166,18 +168,23 @@ def test_paged_flash_decode_compiled_matches_interpret(rng, tpu):
     cpu = jax.devices("cpu")[0]
     for W in (1, 3):
         lens = jnp.maximum(kv_len, W)        # verify needs W written entries
+        qg, ql, qr = f(B, W, Hq, hd), f(B, W, Hq, r), f(B, W, Hq, rh)
         cases = {
             f"gqa W={W}": (paged_flash_verify, _xla_gqa,
-                           (f(B, W, Hq, hd), kpool, vpool), {}),
+                           (qg, stacked(kpool), stacked(vpool)),
+                           (qg, kpool, vpool), {}),
             f"mla W={W}": (paged_flash_verify_mla, _xla_mla,
-                           (f(B, W, Hq, r), f(B, W, Hq, rh), ckv, kr),
-                           {"scale": scale}),
+                           (ql, qr, stacked(ckv), stacked(kr)),
+                           (ql, qr, ckv, kr), {"scale": scale}),
         }
-        for name, (fn, xla, args, kw) in cases.items():
+        for name, (fn, xla, kargs, args, kw) in cases.items():
+            kargs = kargs + (tbl, lens, stacked.layer)
+            compiled = np.asarray(fn(*jax.device_put(kargs, tpu), **kw),
+                                  np.float32)
+            interpreted = np.asarray(fn(*jax.device_put(kargs, cpu), **kw),
+                                     np.float32)
             on_tpu = jax.device_put(args + (tbl, lens), tpu)
             on_cpu = jax.device_put(args + (tbl, lens), cpu)
-            compiled = np.asarray(fn(*on_tpu, **kw), np.float32)
-            interpreted = np.asarray(fn(*on_cpu, **kw), np.float32)
             xla_tpu = jax.jit(functools.partial(xla, **kw))(*on_tpu)
             xla_cpu = jax.jit(functools.partial(xla, **kw))(*on_cpu)
             np.testing.assert_allclose(
@@ -206,7 +213,7 @@ def _walk_eqns(jaxpr):
                 yield from _walk_eqns(inner)
 
 
-def test_paged_flash_decode_never_materializes_gathered_kv(rng):
+def test_paged_flash_decode_never_materializes_gathered_kv(rng, stacked):
     """The acceptance assert: no intermediate in the kernel's program has
     the (B, max_blocks*block_tokens, ...) gathered-KV shape — KV moves
     block-by-block through the scalar-prefetched table, never as a
@@ -214,12 +221,12 @@ def test_paged_flash_decode_never_materializes_gathered_kv(rng):
     from repro.kernels.flash_decode.ops import paged_flash_verify
     B, nb, max_blocks, blk, Hq, Hkv, hd = 3, 10, 4, 16, 8, 2, 64
     q = jnp.zeros((B, 1, Hq, hd), jnp.float32)
-    kpool = jnp.zeros((nb, Hkv, blk, hd), jnp.float32)
-    vpool = jnp.zeros((nb, Hkv, blk, hd), jnp.float32)
+    kpool = stacked(jnp.zeros((nb, Hkv, blk, hd), jnp.float32))
+    vpool = stacked(jnp.zeros((nb, Hkv, blk, hd), jnp.float32))
     tbl = jnp.zeros((B, max_blocks), jnp.int32)
     kv_len = jnp.zeros((B,), jnp.int32)
-    jaxpr = jax.make_jaxpr(
-        lambda *a: paged_flash_verify(*a))(q, kpool, vpool, tbl, kv_len)
+    jaxpr = jax.make_jaxpr(lambda *a: paged_flash_verify(*a))(
+        q, kpool, vpool, tbl, kv_len, stacked.layer)
     bad = _gathered_shapes(B, max_blocks, blk)
     for eqn in _walk_eqns(jaxpr.jaxpr):
         for var in eqn.outvars:
@@ -240,14 +247,18 @@ def _mk_fleet(arch, **kw):
     return LocalFleet([arch], **kw)
 
 
-@pytest.mark.parametrize("arch", [ATTN_ARCH, MLA_ARCH])
-def test_flash_paged_decode_tokens_match_xla(arch):
+@pytest.mark.parametrize("arch,depth", [(ATTN_ARCH, 1), (MLA_ARCH, 1),
+                                        (ATTN_ARCH, 3)],
+                         ids=["attn", "mla", "attn_six_layers"])
+def test_flash_paged_decode_tokens_match_xla(arch, depth):
     """decode_impl="flash_paged" produces IDENTICAL tokens to the default
     XLA paged decode (which test_prefix_paged pins against the contiguous
-    cache) — on the GQA arch and the MLA+MoE arch."""
-    base = _mk_fleet(arch, paged=True, warmup=False)
+    cache) — on the GQA arch and the MLA+MoE arch, and with the GQA
+    arch's layers tripled (the kernel reads each of six layers' pools)."""
+    deep = {arch: {"depth_mult": depth}}
+    base = _mk_fleet(arch, paged=True, warmup=False, arch_overrides=deep)
     flash = _mk_fleet(arch, paged=True, decode_impl="flash_paged",
-                      warmup=False)
+                      warmup=False, arch_overrides=deep)
     shared = " ".join(f"sys{i}" for i in range(20))
     prompts = [shared + " question one", "a lone unshared prompt",
                shared + " question two with a longer tail of words",

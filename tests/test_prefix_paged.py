@@ -213,7 +213,7 @@ def test_blockpool_random_refcount_sweep():
 # paged flash-decode kernel
 # ---------------------------------------------------------------------------
 
-def test_paged_flash_decode_vs_references(rng):
+def test_paged_flash_decode_vs_references(rng, stacked):
     from repro.kernels.flash_decode import (decode_reference, gather_kv,
                                             paged_decode_reference,
                                             paged_flash_verify)
@@ -224,7 +224,8 @@ def test_paged_flash_decode_vs_references(rng):
     # each row maps 4 blocks, deliberately scattered and overlapping
     tbl = jnp.asarray([[1, 5, 2, 9], [3, 1, 7, 4], [8, 6, 1, 2]], jnp.int32)
     kv_len = jnp.asarray([64, 50, 17], jnp.int32)
-    out = paged_flash_verify(q[:, None], kpool, vpool, tbl, kv_len)[:, 0]
+    out = paged_flash_verify(q[:, None], stacked(kpool), stacked(vpool), tbl,
+                             kv_len, stacked.layer)[:, 0]
     ref = paged_decode_reference(q, kpool, vpool, tbl, kv_len)
     np.testing.assert_allclose(out, ref, atol=2e-5, rtol=2e-5)
     # the paged path equals the contiguous oracle on the gathered view
@@ -254,6 +255,15 @@ def mla_pair():
     return _mk_fleet(MLA_ARCH, False), _mk_fleet(MLA_ARCH, True)
 
 
+@pytest.fixture(scope="module")
+def deep_attn_pair():
+    """Six layers in the group: each step's layer scan carries the pools
+    through six in-place updates."""
+    deep = {ATTN_ARCH: {"depth_mult": 3}}
+    return (_mk_fleet(ATTN_ARCH, False, arch_overrides=deep),
+            _mk_fleet(ATTN_ARCH, True, arch_overrides=deep))
+
+
 def _rand_prompts(rnd, n, shared=None):
     out = []
     for _ in range(n):
@@ -265,7 +275,8 @@ def _rand_prompts(rnd, n, shared=None):
     return out
 
 
-@pytest.mark.parametrize("pair_fx", ["attn_pair", "mla_pair"])
+@pytest.mark.parametrize("pair_fx", ["attn_pair", "mla_pair",
+                                     "deep_attn_pair"])
 def test_paged_tokens_match_contiguous_random_sweep(pair_fx, request):
     """The acceptance bar: random admission orders and prompt lengths
     (incl. shared prefixes, so the cached suffix-prefill path is hit)

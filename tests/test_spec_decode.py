@@ -88,42 +88,46 @@ def _tbl_and_lens(rng, *, B, nb, max_blocks, blk, W):
     return tbl, kv_len
 
 
-def test_paged_flash_verify_bitwise_matches_decode_steps(rng):
+def test_paged_flash_verify_bitwise_matches_decode_steps(rng, stacked):
     from repro.kernels.flash_decode import paged_flash_verify
     B, nb, max_blocks, blk, Hq, Hkv, hd, W = 4, 12, 4, 16, 8, 2, 64, 3
-    kpool = jnp.asarray(rng.standard_normal((nb, Hkv, blk, hd)), jnp.float32)
-    vpool = jnp.asarray(rng.standard_normal((nb, Hkv, blk, hd)), jnp.float32)
+    kpool = stacked(jnp.asarray(rng.standard_normal((nb, Hkv, blk, hd)),
+                                jnp.float32))
+    vpool = stacked(jnp.asarray(rng.standard_normal((nb, Hkv, blk, hd)),
+                                jnp.float32))
     tbl, kv_len = _tbl_and_lens(rng, B=B, nb=nb, max_blocks=max_blocks,
                                 blk=blk, W=W)
     q = jnp.asarray(rng.standard_normal((B, W, Hq, hd)), jnp.float32)
-    out = np.asarray(paged_flash_verify(q, kpool, vpool, tbl, kv_len))
+    out = np.asarray(paged_flash_verify(q, kpool, vpool, tbl, kv_len,
+                                        stacked.layer))
     assert out.shape == (B, W, Hq, hd)
     for t in range(W):
         # position t's frontier == the decode step that would see
         # kv_len - (W - 1 - t) written entries
         step = np.asarray(paged_flash_verify(
-            q[:, t:t + 1], kpool, vpool, tbl, kv_len - (W - 1 - t)))
+            q[:, t:t + 1], kpool, vpool, tbl, kv_len - (W - 1 - t),
+            stacked.layer))
         np.testing.assert_array_equal(out[:, t], step[:, 0],
                                       err_msg=f"t={t}")
 
 
-def test_paged_flash_verify_mla_bitwise_matches_decode_steps(rng):
+def test_paged_flash_verify_mla_bitwise_matches_decode_steps(rng, stacked):
     from repro.kernels.flash_decode import paged_flash_verify_mla
     B, nb, max_blocks, blk, H, r, rh, W = 3, 10, 4, 16, 8, 64, 32, 4
     scale = 1.0 / np.sqrt(96.0)
-    ckv = jnp.asarray(rng.standard_normal((nb, blk, r)), jnp.float32)
-    kr = jnp.asarray(rng.standard_normal((nb, blk, rh)), jnp.float32)
+    ckv = stacked(jnp.asarray(rng.standard_normal((nb, blk, r)), jnp.float32))
+    kr = stacked(jnp.asarray(rng.standard_normal((nb, blk, rh)), jnp.float32))
     tbl, kv_len = _tbl_and_lens(rng, B=B, nb=nb, max_blocks=max_blocks,
                                 blk=blk, W=W)
     ql = jnp.asarray(rng.standard_normal((B, W, H, r)), jnp.float32)
     qr = jnp.asarray(rng.standard_normal((B, W, H, rh)), jnp.float32)
     out = np.asarray(paged_flash_verify_mla(ql, qr, ckv, kr, tbl, kv_len,
-                                            scale=scale))
+                                            stacked.layer, scale=scale))
     assert out.shape == (B, W, H, r)
     for t in range(W):
         step = np.asarray(paged_flash_verify_mla(
             ql[:, t:t + 1], qr[:, t:t + 1], ckv, kr, tbl,
-            kv_len - (W - 1 - t), scale=scale))
+            kv_len - (W - 1 - t), stacked.layer, scale=scale))
         np.testing.assert_array_equal(out[:, t], step[:, 0],
                                       err_msg=f"t={t}")
 
@@ -149,6 +153,24 @@ def test_spec_decode_tokens_match_plain(arch, decode_impl, ref_tokens):
     assert sched.spec_offered > 0
     assert sched.spec_accepted == sched.spec_offered   # aligned draft
     assert sched.spec_tokens_per_round > 1.0
+    assert sched.pool.live_refs() == 0
+
+
+@pytest.mark.parametrize("decode_impl", ["xla", "flash_paged"])
+def test_spec_deep_target_token_exact(decode_impl):
+    """A six-layer target verifies the proposals of its two-layer draft:
+    the verify step and the fused draft scan each carry their own pools
+    through their layer loops, and the output equals the plain six-layer
+    fleet's bitwise."""
+    deep = {ATTN_ARCH: {"depth_mult": 3}}
+    plain = _mk_fleet(ATTN_ARCH, paged=True, decode_impl=decode_impl,
+                      arch_overrides=deep)
+    want = [r["tokens"] for r in plain.generate(ATTN_ARCH, PROMPTS)]
+    fleet = _mk_fleet(ATTN_ARCH, paged=True, decode_impl=decode_impl,
+                      speculative=_spec(ATTN_ARCH, k=3), arch_overrides=deep)
+    assert [r["tokens"] for r in fleet.generate(ATTN_ARCH, PROMPTS)] == want
+    sched = fleet.schedulers[ATTN_ARCH]
+    assert sched.spec_rounds > 0
     assert sched.pool.live_refs() == 0
 
 
