@@ -54,7 +54,7 @@ def main(argv=None) -> int:
     bench = C.prepare(cell, seeds[0], devices, annotate=False)
     runs = []
     for seed in seeds:
-        system.install_weights(bench.fleet, cell.config, seed)
+        system.install_weights(bench.fleet, cell.config, cell.family, seed)
         sched = generate(cell.traffic, cell.config, seed, a.seconds)
         system.warm_up(bench.fleet, cell.config, cell.traffic, sched)
         bench.taps.finished.clear()

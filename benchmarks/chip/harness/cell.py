@@ -78,10 +78,18 @@ class Bench:
         return {p["arch"]: self.cell.config["models"][m]
                 for m, p in r["profiles"].items()}
 
+    def family_of(self, arch: str):
+        """The family that counts the work of the member serving ``arch``.
+        Every member of a configuration is of the one family its
+        ``reference`` names; readers ask per member all the same, so that
+        they hold for any configuration."""
+        return self.cell.family
+
 
 def prepare(cell: spec.Cell, seed: int, devices, *, annotate: bool) -> Bench:
-    router, fleet = system.build(cell.config, cell.traffic, devices)
-    system.install_weights(fleet, cell.config, seed)
+    router, fleet = system.build(cell.config, cell.traffic, cell.family,
+                                 devices)
+    system.install_weights(fleet, cell.config, cell.family, seed)
     taps = system.Taps()
     system.tap(fleet, taps, annotate=annotate)
     return Bench(cell, devices, router, fleet, taps, annotate)
@@ -206,7 +214,7 @@ def layer_context(bench: Bench, win: Window, tr: Optional[T.Trace]):
         ok=[r for r in win.records if r.done and not r.error],
         delta=win.delta, trace=tr, decode_calls=win.decode_calls,
         prefill_calls=win.prefill_calls,
-        models_by_arch=bench.models_by_arch,
+        models_by_arch=bench.models_by_arch, family_of=bench.family_of,
         block_tokens=f["block_tokens"], chips=len(bench.devices),
         peak=peaks_for(bench.devices[0].device_kind))
 

@@ -1,72 +1,11 @@
-"""Operations and bytes the work needs, from shapes alone.
+"""The chip's least time for a piece of work.
 
-These count what the algorithm requires, not what an implementation
-happens to do: a kernel that pads its heads or reads dead pages does more
-than this and reads the lower share of its roofline for it.  ``m`` is a
-model entry of a configuration file (Hugging Face key names).
+What a piece of work needs (its operations and bytes, from shapes alone)
+is counted by the model's family (``families/<name>.py``); this turns it
+into the time the chip's peaks allow.
 """
 
 from __future__ import annotations
-
-import math
-from typing import Iterable
-
-
-def dims(m: dict) -> dict:
-    d = m["hidden_size"]
-    H = m["num_attention_heads"]
-    Hkv = m["num_key_value_heads"]
-    hd = m.get("head_dim") or d // H
-    return dict(d=d, H=H, Hkv=Hkv, hd=hd, F=m["intermediate_size"],
-                L=m["num_hidden_layers"], V=m["vocab_size"])
-
-
-def layer_matmul_params(m: dict) -> int:
-    """Weights one token multiplies through in one decoder layer: the
-    q/k/v/o projections and the gated MLP."""
-    x = dims(m)
-    attn = x["d"] * x["H"] * x["hd"] * 2 + x["d"] * x["Hkv"] * x["hd"] * 2
-    return attn + 3 * x["d"] * x["F"]
-
-
-def matmul_params(m: dict) -> int:
-    """All weights a decoded token multiplies through: every layer plus
-    the output head (the embedding lookup multiplies nothing)."""
-    x = dims(m)
-    return x["L"] * layer_matmul_params(m) + x["V"] * x["d"]
-
-
-def attention_flops(m: dict, kv_len: int) -> int:
-    """One query token attending over kv_len keys, all layers: q.k and
-    p.v, 2 flops a multiply-add each."""
-    x = dims(m)
-    return x["L"] * 4 * x["H"] * x["hd"] * kv_len
-
-
-def decode_token_flops(m: dict, kv_len: int) -> int:
-    return 2 * matmul_params(m) + attention_flops(m, kv_len)
-
-
-def decode_step_flops(m: dict, kv_lens: Iterable[int]) -> int:
-    """A decode step over the live rows, each at its own kv length
-    (including the token written this step)."""
-    return sum(decode_token_flops(m, int(n)) for n in kv_lens)
-
-
-def paged_attention_cost(m: dict, kv_lens: Iterable[int],
-                         block_tokens: int, dtype_bytes: int = 2):
-    """(flops, bytes) of ONE layer's paged decode attention over the live
-    rows: q and out of every head, and the live pages of every KV head,
-    keys and values."""
-    x = dims(m)
-    flops = nbytes = 0
-    for n in kv_lens:
-        n = int(n)
-        pages = math.ceil(n / block_tokens)
-        flops += 4 * x["H"] * x["hd"] * n
-        nbytes += 2 * x["H"] * x["hd"] * dtype_bytes          # q, out
-        nbytes += 2 * x["Hkv"] * pages * block_tokens * x["hd"] * dtype_bytes
-    return flops, nbytes
 
 
 def roofline_seconds(flops: float, nbytes: float, peak: dict) -> float:

@@ -2,9 +2,12 @@
 
 Everything is looked up by name from ``BENCHMARK.json`` at the checkout
 root: a configuration is ``configs/<name>.json``, a traffic mix is
-``traffic/<name>.json``, a per-layer metric is ``metrics/<name>.py`` and a
-configuration's plain reference is ``references/<reference>.py``.  A later
-change adds a cell, a mix or a metric by adding such a file and an entry in
+``traffic/<name>.json``, a per-layer metric is ``metrics/<name>.py``, and
+a configuration's architecture is named by its ``reference``: its plain
+reference is ``references/<reference>.py`` and its family (the checks,
+weight layout and work counts of that architecture) is
+``families/<reference>.py``.  A later change adds a cell, a mix, a metric
+or an architecture by adding such files and an entry in
 ``BENCHMARK.json``; nothing here names one.
 """
 
@@ -24,6 +27,22 @@ class SpecError(RuntimeError):
     pass
 
 
+class ConfigMismatch(RuntimeError):
+    """The program does not run the model a configuration states."""
+
+
+def differing(arch_cfg, m: dict, fields) -> List[str]:
+    """Each (program field, file key) pair whose values differ between the
+    program's model config and a configuration's model entry (a float to
+    within 1e-12 of the file's); a key the file leaves out reads False."""
+    out = []
+    for ours, theirs in fields:
+        a, b = getattr(arch_cfg, ours), m.get(theirs, False)
+        if (a != b) if not isinstance(a, float) else abs(a - b) > 1e-12 * abs(b):
+            out.append(f"{ours} {a!r} != {theirs} {b!r}")
+    return out
+
+
 @dataclass
 class Cell:
     name: str
@@ -32,6 +51,7 @@ class Cell:
     traffic: dict
     end_to_end: List[dict]
     per_layer: List[dict]
+    family: object
     readers: Dict[str, object] = field(default_factory=dict)
 
 
@@ -80,6 +100,14 @@ def load_reference(name: str, bench_dir: Path = BENCH_DIR):
                        "chipbench_reference_" + name)
 
 
+def load_family(name: str, bench_dir: Path = BENCH_DIR):
+    """An architecture's family: a module with ``check``,
+    ``program_params`` and the work counts its readers use (see
+    ``families/dense_gqa.py``)."""
+    return load_module(bench_dir / "families" / f"{name}.py",
+                       "chipbench_family_" + name)
+
+
 def reports(metric: dict, cell: str) -> bool:
     wl = metric.get("workloads")
     return wl is None or cell in wl
@@ -93,11 +121,12 @@ def load_cell(cell: str, root: Path = ROOT,
         raise SpecError(f"no workload {cell!r} in BENCHMARK.json; have "
                         + ", ".join(sorted(by_name)))
     w = by_name[cell]
-    c = Cell(name=cell, chips=int(w["chips"]),
-             config=load_config(w["config"], bench_dir),
+    config = load_config(w["config"], bench_dir)
+    c = Cell(name=cell, chips=int(w["chips"]), config=config,
              traffic=load_traffic(w["traffic"], bench_dir),
              end_to_end=[m for m in bench["end_to_end"] if reports(m, cell)],
-             per_layer=[m for m in bench["per_layer"] if reports(m, cell)])
+             per_layer=[m for m in bench["per_layer"] if reports(m, cell)],
+             family=load_family(config["reference"], bench_dir))
     for m in c.per_layer:
         c.readers[m["name"]] = load_reader(m["name"], bench_dir)
     return c

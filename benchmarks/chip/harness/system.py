@@ -3,9 +3,13 @@ them, with the benchmark's weights put in and the cell's shapes warmed up.
 
 This is the one module that touches the program.  It takes from it the
 entry point (``build_router``), the fleet's members and lanes, and their
-counters; it hands it weights made from the seed.  Spans around the calls
-into each layer are the benchmark's own ``TraceAnnotation``s, wrapped
-around those calls from outside: the program is not edited.
+counters; it hands it weights made from the seed.  What is particular to
+an architecture (which configuration keys the program must match, which
+leaves make its parameter tree) is asked of the configuration's family
+(``families/<reference>.py``); nothing here names a key or a leaf.  Spans
+around the calls into each layer are the benchmark's own
+``TraceAnnotation``s, wrapped around those calls from outside: the
+program is not edited.
 """
 
 from __future__ import annotations
@@ -19,24 +23,11 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from harness import rules
+from harness import rules, spec
 from harness import weights as W
 from harness.traffic import Schedule, prompt_cap
 
-# the fleet's configuration fields each configuration file states, by the
-# program's name and the file's (Hugging Face) name
-MODEL_FIELDS = (("d_model", "hidden_size"), ("n_heads", "num_attention_heads"),
-                ("n_kv_heads", "num_key_value_heads"), ("head_dim", "head_dim"),
-                ("d_ff", "intermediate_size"), ("vocab_size", "vocab_size"),
-                ("rope_theta", "rope_theta"), ("norm_eps", "rms_norm_eps"),
-                ("qk_norm", "qk_norm"),
-                ("tie_embeddings", "tie_word_embeddings"), ("dtype", "dtype"))
-
 WARM_SEED = 0x5EED       # warm-up prompts: fixed, and unlike any traffic
-
-
-class ConfigMismatch(RuntimeError):
-    pass
 
 
 @dataclass
@@ -50,50 +41,14 @@ class Taps:
     lock: threading.Lock = field(default_factory=threading.Lock)
 
 
-def check_model(arch_cfg, m: dict, arch: str):
-    """The program must run the model the configuration file states."""
-    groups = arch_cfg.groups
-    layers = sum(g.repeats * len(g.period) for g in groups)
-    bad = []
-    if layers != m["num_hidden_layers"]:
-        bad.append(f"layers {layers} != {m['num_hidden_layers']}")
-    if len(groups) != 1 or len(groups[0].period) != 1 or \
-            groups[0].period[0].mixer != "attn" or \
-            groups[0].period[0].ffn != "dense":
-        bad.append("not a stack of dense attention blocks")
-    for ours, theirs in MODEL_FIELDS:
-        a, b = getattr(arch_cfg, ours), m.get(theirs, False)
-        if (a != b) if not isinstance(a, float) else abs(a - b) > 1e-12 * abs(b):
-            bad.append(f"{ours} {a!r} != {theirs} {b!r}")
-    if bad:
-        raise ConfigMismatch(f"{arch}: the program does not run the "
-                             "configured model: " + "; ".join(bad))
-
-
-def program_params(m: dict, key, dtype):
-    """The benchmark's weights in the program's parameter layout."""
-    w = W.all_weights(m, key, dtype)
-    lw = w["layers"]
-    attn = {k: lw[k] for k in ("wq", "wk", "wv", "wo", "q_norm", "k_norm")
-            if k in lw}
-    p = {"embed": w["embed"], "final_norm": w["final_norm"],
-         "g0": {"b0": {"norm1": lw["attn_norm"], "attn": attn,
-                       "norm2": lw["mlp_norm"],
-                       "ffn": {"w_gate": lw["w_gate"], "w_up": lw["w_up"],
-                               "w_down": lw["w_down"]}}}}
-    if "lm_head" in w:
-        p["lm_head"] = w["lm_head"].T
-    return p
-
-
 def _shape_tree(tree):
     return jax.tree.map(lambda x: (tuple(x.shape), str(x.dtype)), tree)
 
 
-def install_weights(fleet, config: dict, seed: int):
-    """Replace each member's parameters by the benchmark's, made on the
-    device in one jitted call per member, sharded as the program shards
-    its own."""
+def install_weights(fleet, config: dict, family, seed: int):
+    """Replace each member's parameters by the benchmark's, laid out by
+    the configuration's ``family``, made on the device in one jitted call
+    per member, sharded as the program shards its own."""
     for model, prof in config["router"]["profiles"].items():
         arch = prof["arch"]
         m = config["models"][model]
@@ -103,20 +58,21 @@ def install_weights(fleet, config: dict, seed: int):
         member.params = None
         gc.collect()
         dtype = jnp.dtype(m["dtype"])
-        make = jax.jit(lambda k, m=m: program_params(m, k, dtype),
+        make = jax.jit(lambda k, m=m: family.program_params(m, k, dtype),
                        out_shardings=shard)
         got = jax.eval_shape(make, W.member_key(seed, model))
         if _shape_tree(got) != want:
-            raise ConfigMismatch(f"{arch}: benchmark weights do not fit "
-                                 "the program's parameter tree")
+            raise spec.ConfigMismatch(f"{arch}: benchmark weights do not "
+                                      "fit the program's parameter tree")
         member.params = make(W.member_key(seed, model))
     jax.block_until_ready([mm.params for mm in fleet.members.values()])
 
 
-def build(config: dict, traffic: dict, devices, *, reduced: bool = False,
-          **fleet_opts):
+def build(config: dict, traffic: dict, family, devices, *,
+          reduced: bool = False, **fleet_opts):
     """Router + fleet through the program's entry point, no warm-up, and
-    each member checked against the model its configuration states.
+    each member checked by the configuration's ``family`` against the
+    model its configuration states.
     ``reduced`` serves the program's small presets and ``fleet_opts`` go
     to the fleet as they are (CPU tests only)."""
     from repro.launch.serve import build_router
@@ -129,7 +85,7 @@ def build(config: dict, traffic: dict, devices, *, reduced: bool = False,
         devices=devices, seed=0, warmup=False, **fleet_opts)
     for model, prof in config["router"]["profiles"].items():
         arch = prof["arch"]
-        check_model(fleet.members[arch].cfg, config["models"][model], arch)
+        family.check(fleet.members[arch].cfg, config["models"][model], arch)
     return router, fleet
 
 

@@ -1,11 +1,13 @@
-"""Seeded weights of a dense GQA decoder, in the benchmark's own layout.
+"""Seeded weights: the keys and the draws that every model family shares.
 
-Each leaf is drawn from its own key: the member's key folded with the
-leaf's index, and for a per-layer leaf with the layer.  Embedding and
-output-head rows come in blocks of ``VOCAB_BLOCK`` rows, each from its own
-key.  So the whole tree can be made in one jitted call on the device (to
-serve), and any one layer or block of rows can be made again alone, with
-the same bits (for the reference, layer by layer).
+A family (``families/<name>.py``) lays out its own leaves; this module
+gives it the keys and the exact draws to make them from.  Each leaf is
+drawn from its own key: the member's key folded with the leaf's index,
+and for a per-layer leaf with the layer (``leaf_key``).  A vocabulary
+table (embedding, output head) comes in blocks of ``VOCAB_BLOCK`` rows,
+each from its own key.  So a whole tree can be made in one jitted call on
+the device (to serve), and any one layer or block of rows can be made
+again alone, with the same bits (for the reference, layer by layer).
 
 Values are uniform with the stated standard deviation, built from the
 random bits by exact float steps (an integer times a power of two, minus
@@ -25,10 +27,6 @@ import numpy as np
 VOCAB_BLOCK = 128
 CHUNK_BLOCKS = 64          # vocab blocks made together
 
-LAYER_LEAVES = ("attn_norm", "wq", "wk", "wv", "wo", "q_norm", "k_norm",
-                "mlp_norm", "w_gate", "w_up", "w_down")
-TOP_LEAVES = ("embed", "final_norm", "lm_head")
-
 
 def seed_key(seed: int):
     """A threefry key from any whole number (it may exceed 64 bits or be
@@ -43,96 +41,64 @@ def member_key(seed: int, member: str):
                               & 0x7FFFFFFF)
 
 
+def leaf_key(key, index: int, layer=None):
+    """The key of a family's leaf ``index``; of its ``layer`` for a
+    per-layer leaf (``layer`` may be traced)."""
+    k = jax.random.fold_in(key, index)
+    return k if layer is None else jax.random.fold_in(k, layer)
+
+
 def _unit(key, shape):
     """Uniform on [-1, 1) with 2**-23 resolution, bit-exact everywhere."""
     bits = jax.random.bits(key, shape, jnp.uint32)
     return (bits >> 8).astype(jnp.float32) * (2.0 ** -23) - 1.0
 
 
-def _draw(key, shape, std):
+def draw(key, shape, std):
     return _unit(key, shape) * (math.sqrt(3.0) * std)
 
 
-def _norm(key, shape):
+def norm(key, shape):
+    """A norm's gain: 1 +- 0.1."""
     return 1.0 + 0.1 * _unit(key, shape)
 
 
-def layer_shapes(m: dict) -> dict:
-    d, H, Hkv = m["hidden_size"], m["num_attention_heads"], \
-        m["num_key_value_heads"]
-    hd = m.get("head_dim") or d // H
-    F = m["intermediate_size"]
-    out = {"attn_norm": (d,), "wq": (d, H * hd), "wk": (d, Hkv * hd),
-           "wv": (d, Hkv * hd), "wo": (H * hd, d), "mlp_norm": (d,),
-           "w_gate": (d, F), "w_up": (d, F), "w_down": (F, d)}
-    if m.get("qk_norm"):
-        out["q_norm"] = (hd,)
-        out["k_norm"] = (hd,)
-    return out
+def fan_in(key, shape):
+    """A matmul weight, std 1/sqrt(its first dim)."""
+    return draw(key, shape, 1.0 / math.sqrt(shape[0]))
 
 
-def _leaf(key, name: str, shape):
-    if name.endswith("norm"):
-        return _norm(key, shape)
-    return _draw(key, shape, 1.0 / math.sqrt(shape[0]))
+def vocab_blocks(rows: int) -> int:
+    if rows % VOCAB_BLOCK:
+        raise ValueError(f"vocab {rows} is not a multiple of {VOCAB_BLOCK}")
+    return rows // VOCAB_BLOCK
 
 
-def layer_weights(m: dict, key, layer, dtype=jnp.bfloat16) -> dict:
-    """One decoder layer's weights (``layer`` may be traced)."""
-    return {n: _leaf(jax.random.fold_in(
-        jax.random.fold_in(key, LAYER_LEAVES.index(n)), layer), n, s)
-        .astype(dtype) for n, s in layer_shapes(m).items()}
+def vocab_chunks(rows: int) -> int:
+    return -(-vocab_blocks(rows) // CHUNK_BLOCKS)
 
 
-def vocab_blocks(m: dict) -> int:
-    V = m["vocab_size"]
-    if V % VOCAB_BLOCK:
-        raise ValueError(f"vocab {V} is not a multiple of {VOCAB_BLOCK}")
-    return V // VOCAB_BLOCK
-
-
-def vocab_rows(m: dict, key, leaf: str, first_block, nblocks: int,
+def block_rows(key, first_block, nblocks: int, width: int, std,
                dtype=jnp.bfloat16):
-    """Rows [first_block*VOCAB_BLOCK, +nblocks*VOCAB_BLOCK) of the
-    embedding or of the output head (stored (V, d), row per token)."""
-    d = m["hidden_size"]
-    std = 0.02 if leaf == "embed" else 1.0 / math.sqrt(d)
-    k = jax.random.fold_in(key, len(LAYER_LEAVES) + TOP_LEAVES.index(leaf))
+    """Rows [first_block*VOCAB_BLOCK, +nblocks*VOCAB_BLOCK) of a table
+    whose every block of rows is drawn from ``key`` folded with the
+    block's index."""
     blocks = first_block + jnp.arange(nblocks)
-    rows = jax.vmap(lambda b: _draw(jax.random.fold_in(k, b),
-                                    (VOCAB_BLOCK, d), std))(blocks)
-    return rows.reshape(nblocks * VOCAB_BLOCK, d).astype(dtype)
+    rows = jax.vmap(lambda b: draw(jax.random.fold_in(key, b),
+                                   (VOCAB_BLOCK, width), std))(blocks)
+    return rows.reshape(nblocks * VOCAB_BLOCK, width).astype(dtype)
 
 
-def final_norm(m: dict, key, dtype=jnp.bfloat16):
-    k = jax.random.fold_in(key, len(LAYER_LEAVES)
-                           + TOP_LEAVES.index("final_norm"))
-    return _norm(k, (m["hidden_size"],)).astype(dtype)
-
-
-def vocab_chunks(m: dict) -> int:
-    return -(-vocab_blocks(m) // CHUNK_BLOCKS)
-
-
-def vocab_chunk(m: dict, key, leaf: str, chunk, dtype=jnp.bfloat16):
+def chunk_rows(key, chunk, width: int, std, dtype=jnp.bfloat16):
     """CHUNK_BLOCKS blocks of rows from block chunk*CHUNK_BLOCKS on; the
-    last chunk runs past the vocabulary, and its extra rows are unused."""
-    return vocab_rows(m, key, leaf, chunk * CHUNK_BLOCKS, CHUNK_BLOCKS, dtype)
+    last chunk runs past the table, and its extra rows are unused."""
+    return block_rows(key, chunk * CHUNK_BLOCKS, CHUNK_BLOCKS, width, std,
+                      dtype)
 
 
-def all_weights(m: dict, key, dtype=jnp.bfloat16) -> dict:
-    """The whole tree: top leaves, and every layer leaf stacked (L, ...).
-    Layers and vocabulary chunks are made one after another (``lax.map``),
-    so the temporaries are one layer's or one chunk's."""
-    V, d = m["vocab_size"], m["hidden_size"]
-
-    def rows(leaf):
-        r = jax.lax.map(lambda c: vocab_chunk(m, key, leaf, c, dtype),
-                        jnp.arange(vocab_chunks(m)))
-        return r.reshape(-1, d)[:V]
-    w = {"embed": rows("embed"), "final_norm": final_norm(m, key, dtype),
-         "layers": jax.lax.map(lambda l: layer_weights(m, key, l, dtype),
-                               jnp.arange(m["num_hidden_layers"]))}
-    if not m.get("tie_word_embeddings"):
-        w["lm_head"] = rows("lm_head")
-    return w
+def table(key, rows: int, width: int, std, dtype=jnp.bfloat16):
+    """The whole (rows, width) table, made one chunk after another
+    (``lax.map``), so the temporaries are one chunk's."""
+    r = jax.lax.map(lambda c: chunk_rows(key, c, width, std, dtype),
+                    jnp.arange(vocab_chunks(rows)))
+    return r.reshape(-1, width)[:rows]

@@ -3,8 +3,10 @@ attention over its measured device time.
 
 The least time of one call (one layer of one step) is the larger of its
 FLOPs over the bf16 peak and its bytes over HBM bandwidth, counting what
-attention needs: q and out of every head and the live pages of every KV
-head (harness.flops).  Averaged over the calls the host launched in the
+attention needs as the member's family counts it
+(``paged_attention_cost``: for a dense GQA member, q and out of every
+head and the live pages of every KV head), once for each of its
+``attention_layers``.  Averaged over the calls the host launched in the
 traced window, times the kernel's events on the device in it, over their
 summed device time."""
 
@@ -28,9 +30,9 @@ def read(ctx):
         return None
     least = calls = 0
     for arch, lens in ctx.decode_calls:
-        m = ctx.models_by_arch[arch]
-        fl, by = F.paged_attention_cost(m, lens, ctx.block_tokens)
-        layers = m["num_hidden_layers"]
+        m, fam = ctx.models_by_arch[arch], ctx.family_of(arch)
+        fl, by = fam.paged_attention_cost(m, lens, ctx.block_tokens)
+        layers = fam.attention_layers(m)
         least += layers * F.roofline_seconds(fl, by, ctx.peak)
         calls += layers
     return 100.0 * (least / calls) * n_dev / t_dev

@@ -12,7 +12,8 @@ Straight ``jax.numpy`` in float32 at ``Precision.HIGHEST``: no cache, no
 kernels, no batching of rows.  It runs one sequence at a time, padded to a
 fixed length (causal attention makes the padding invisible to the real
 positions), and layer by layer, making each layer's weights again from the
-seed, so that it fits beside nothing else.
+seed, so that it fits beside nothing else.  The weights' layout is the
+benchmark's own, from this architecture's family (``families/dense_gqa.py``).
 
 ``mode="int8"`` is the control: the same computation with int8 where the
 configuration serves bfloat16.  Every matmul weight is rounded to int8 per
@@ -32,8 +33,12 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-sys.path.insert(0, str(Path(__file__).resolve().parents[1]))
+BENCH = Path(__file__).resolve().parents[1]
+sys.path.insert(0, str(BENCH))
+from harness import spec  # noqa: E402
 from harness import weights as W  # noqa: E402
+
+FAM = spec.load_family("dense_gqa", BENCH)
 
 HI = jax.lax.Precision.HIGHEST
 
@@ -54,7 +59,7 @@ class Reference:
         d, H = m["hidden_size"], m["num_attention_heads"]
         self.hd = m.get("head_dim") or d // H
         self.eps = m["rms_norm_eps"]
-        self.nchunks = W.vocab_chunks(m)
+        self.nchunks = FAM.vocab_chunks(m)
         self._layer_w = jax.jit(self._layer_weights)
         self._layer = jax.jit(self._layer_fn)
         self._embed = jax.jit(self._embed_chunk)
@@ -80,12 +85,12 @@ class Reference:
         return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
 
     def _layer_weights(self, layer):
-        w = W.layer_weights(self.m, self.key, layer, self.dt)
+        w = FAM.layer_weights(self.m, self.key, layer, self.dt)
         return jax.tree.map(lambda a: a.astype(jnp.float32), w)
 
     def _rows(self, leaf, chunk):
-        return W.vocab_chunk(self.m, self.key, leaf, chunk,
-                             self.dt).astype(jnp.float32)
+        return FAM.vocab_chunk(self.m, self.key, leaf, chunk,
+                               self.dt).astype(jnp.float32)
 
     def _embed_chunk(self, chunk, ids, h):
         lo = chunk * W.CHUNK_BLOCKS * W.VOCAB_BLOCK
@@ -148,7 +153,7 @@ class Reference:
         for layer in range(m["num_hidden_layers"]):
             w = self._layer_w(layer)
             hs = [self._layer(w, h) for h in hs]
-        g = W.final_norm(m, self.key, self.dt).astype(jnp.float32)
+        g = FAM.final_norm(m, self.key, self.dt).astype(jnp.float32)
         x = jnp.concatenate([self.lo(self.rms(h[jnp.asarray(p)], g))
                              for h, p in zip(hs, positions)])
         logits = jnp.concatenate([self._head(c, x)

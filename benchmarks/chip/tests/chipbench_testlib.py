@@ -24,16 +24,22 @@ MAX_SEQ = 160
 GEN = 8
 
 
-def small_model(arch: str, dtype: str = "bfloat16") -> dict:
+DENSE = spec.load_family("dense_gqa")
+
+
+def small_model(arch: str, family=DENSE, dtype: str = "bfloat16") -> dict:
+    """The configuration entry of the program's reduced preset of
+    ``arch``, as ``family`` states a model."""
     from repro.configs import get_reduced
-    c = get_reduced(arch)
-    return {"hidden_size": c.d_model, "intermediate_size": c.d_ff,
-            "num_hidden_layers": c.groups[0].repeats,
-            "num_attention_heads": c.n_heads,
-            "num_key_value_heads": c.n_kv_heads, "head_dim": c.head_dim,
-            "vocab_size": c.vocab_size, "rope_theta": c.rope_theta,
-            "rms_norm_eps": c.norm_eps, "tie_word_embeddings": c.tie_embeddings,
-            "qk_norm": c.qk_norm, "dtype": dtype}
+    return family.entry_for(get_reduced(arch), dtype)
+
+
+def dense_cfg(layers: int, **fields):
+    """A program model of ``layers`` dense attention blocks."""
+    from repro.models.config import BlockSpec, LayerGroup, ModelConfig
+    return ModelConfig(name="stand-in", family="dense",
+                       groups=(LayerGroup((BlockSpec("attn", "dense"),),
+                                          layers),), **fields)
 
 
 # At the reduced presets (CPU run): sound runs' mean logit gap read
@@ -43,8 +49,9 @@ SMALL_LIMIT = 0.005
 
 def small_config(name: str, limit: float = SMALL_LIMIT) -> dict:
     cfg = copy.deepcopy(spec.load_config(name))
+    family = spec.load_family(cfg["reference"])
     for model, prof in cfg["router"]["profiles"].items():
-        cfg["models"][model] = small_model(prof["arch"])
+        cfg["models"][model] = small_model(prof["arch"], family)
     cfg["fleet"].update(batch=4, max_seq=MAX_SEQ, model_axis=1)
     if cfg["fleet"]["decode_impl"] != "flash_paged":
         cfg["fleet"]["decode_impl"] = "xla"
@@ -66,28 +73,29 @@ def small_cell(workload: str, limit: float = SMALL_LIMIT) -> spec.Cell:
     import json
     bench = json.loads((ROOT / "BENCHMARK.json").read_text())
     w = {x["name"]: x for x in bench["workloads"]}[workload]
-    return spec.Cell(name=workload, chips=1,
-                     config=small_config(w["config"], limit),
+    cfg = small_config(w["config"], limit)
+    return spec.Cell(name=workload, chips=1, config=cfg,
                      traffic=small_traffic(w["traffic"]),
-                     end_to_end=bench["end_to_end"], per_layer=[])
+                     end_to_end=bench["end_to_end"], per_layer=[],
+                     family=spec.load_family(cfg["reference"]))
 
 
 # A mid-size dense GQA member the CPU can serve: width 256, 4 layers,
 # vocab 2048, where an int8 path's logits already part from bfloat16's.
-MID = dict(hidden_size=256, intermediate_size=512, num_hidden_layers=4,
-           num_attention_heads=8, num_key_value_heads=4, head_dim=32,
-           vocab_size=2048)
 MID_FIELDS = dict(d_model=256, d_ff=512, n_heads=8, n_kv_heads=4,
                   head_dim=32, vocab_size=2048, depth_mult=2)
 
 
 def mid_cell(workload: str, limit: float) -> spec.Cell:
-    """A small cell whose members are served at ``MID`` widths (the
+    """A small cell whose members are served at ``MID_FIELDS`` widths (the
     reduced presets' two layers doubled), 32 tokens out and four answers
     a model compared: 128 served tokens a model."""
+    from repro.configs import get_reduced
+    from repro.serving.fleet import _apply_arch_overrides
     cell = small_cell(workload, limit)
-    for model in cell.config["models"]:
-        cell.config["models"][model].update(MID)
+    for model, prof in cell.config["router"]["profiles"].items():
+        mid = _apply_arch_overrides(get_reduced(prof["arch"]), MID_FIELDS)
+        cell.config["models"][model] = cell.family.entry_for(mid)
     cell.config["correct"]["sample_per_model"] = 4
     cell.traffic["gen_tokens"] = 32
     return cell
@@ -104,10 +112,10 @@ def prepare_small(cell, seed: int, **fleet_opts):
     import jax
     from harness import cell as C
     from harness import system
-    router, fleet = system.build(cell.config, cell.traffic,
+    router, fleet = system.build(cell.config, cell.traffic, cell.family,
                                  jax.devices()[:1], reduced=True,
                                  **fleet_opts)
-    system.install_weights(fleet, cell.config, seed)
+    system.install_weights(fleet, cell.config, cell.family, seed)
     taps = system.Taps()
     system.tap(fleet, taps, annotate=False)
     return C.Bench(cell, jax.devices()[:1], router, fleet, taps, False)

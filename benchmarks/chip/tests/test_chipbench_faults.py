@@ -100,7 +100,7 @@ def no_exchange(bench, shards=4):
             keep = w.shape[1] // shards
             b[grp][leaf] = w.at[:, keep:, :].set(0)
     return lambda: system.install_weights(bench.fleet, bench.cell.config,
-                                          SEED)
+                                          bench.cell.family, SEED)
 
 
 def test_sound_run_is_correct(chat):
@@ -126,7 +126,7 @@ def test_a_broken_run_is_not_correct(chat, fault, number):
 
 
 
-# At MID widths (CPU run), over six seeds: the bfloat16 program's mean
+# At TL.MID_FIELDS widths (CPU run), over six seeds: the bfloat16 program's mean
 # gap read 0.00001-0.00016 a model, the int8 control's 0.00107-0.00273
 # (the larger of the two models, which decides the verdict).  The limit
 # lies between them, with more room above the lower reading.
@@ -145,7 +145,7 @@ def mid():
 @pytest.mark.parametrize("seed", (SEED + 100, SEED + 101, SEED + 102))
 def test_the_int8_control_is_not_correct(mid, seed):
     cell = mid.cell
-    system.install_weights(mid.fleet, cell.config, seed)
+    system.install_weights(mid.fleet, cell.config, cell.family, seed)
     mid.taps.finished.clear()
     win = C.run_window(mid, generate(cell.traffic, cell.config, seed,
                                      SECONDS), drain_s=60.0)

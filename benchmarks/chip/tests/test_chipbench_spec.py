@@ -3,6 +3,7 @@ run without a chip."""
 
 import json
 import os
+import re
 import subprocess
 import sys
 
@@ -23,7 +24,12 @@ def test_every_named_file_is_found():
         assert {m["name"] for m in cell.end_to_end} >= {"setup_s"}
     for c in bench["configs"]:
         assert (TL.ROOT / c["file"]).is_file()
-        spec.load_reference(spec.load_config(c["name"])["reference"])
+        ref = spec.load_config(c["name"])["reference"]
+        spec.load_reference(ref)
+        fam = spec.load_family(ref)
+        for fn in ("check", "program_params", "decode_step_flops",
+                   "paged_attention_cost", "attention_layers"):
+            assert callable(getattr(fam, fn)), (ref, fn)
 
 
 def test_reduced_names_every_changed_key():
@@ -37,13 +43,15 @@ def test_reduced_names_every_changed_key():
 
 
 def test_a_cell_added_as_files_is_found(tmp_path):
-    """A new configuration, traffic mix and metric are found from their
-    files and a BENCHMARK.json entry, with no code changed."""
+    """A new configuration, architecture family, traffic mix and metric
+    are found from their files and a BENCHMARK.json entry, with no code
+    changed."""
     bdir = tmp_path / "bench"
-    for sub in ("configs", "traffic", "metrics"):
+    for sub in ("configs", "traffic", "metrics", "families"):
         (bdir / sub).mkdir(parents=True)
     (bdir / "configs" / "newcfg.json").write_text(json.dumps(
-        {"name": "newcfg", "reference": "dense_gqa", "router": {}}))
+        {"name": "newcfg", "reference": "newarch", "router": {}}))
+    (bdir / "families" / "newarch.py").write_text("NAME = 'newarch'\n")
     (bdir / "traffic" / "newmix.json").write_text(json.dumps(
         {"kind": "chat", "rate_rps": 1.0}))
     (bdir / "metrics" / "new.metric.py").write_text(
@@ -58,8 +66,27 @@ def test_a_cell_added_as_files_is_found(tmp_path):
     assert cell.traffic["rate_rps"] == 1.0 and cell.traffic["name"] == "newmix"
     assert [m["name"] for m in cell.per_layer] == ["new.metric"]
     assert cell.readers["new.metric"].read(None) == 42.0
+    assert cell.family.NAME == "newarch"
     with pytest.raises(spec.SpecError):
         spec.load_cell("missing", root=tmp_path, bench_dir=bdir)
+
+
+def test_a_configuration_without_its_family_is_refused(tmp_path):
+    """A configuration whose ``reference`` names no ``families/`` file is
+    refused before anything runs, naming the file it looked for."""
+    bdir = tmp_path / "bench"
+    for sub in ("configs", "traffic"):
+        (bdir / sub).mkdir(parents=True)
+    (bdir / "configs" / "newcfg.json").write_text(json.dumps(
+        {"name": "newcfg", "reference": "nosuch", "router": {}}))
+    (bdir / "traffic" / "newmix.json").write_text(json.dumps({"kind": "chat"}))
+    (tmp_path / "BENCHMARK.json").write_text(json.dumps({
+        "workloads": [{"name": "newcfg.newmix", "config": "newcfg",
+                       "traffic": "newmix", "chips": 1, "why": "x"}],
+        "end_to_end": [{"name": "setup_s"}], "per_layer": []}))
+    with pytest.raises(spec.SpecError,
+                       match=re.escape(str(bdir / "families" / "nosuch.py"))):
+        spec.load_cell("newcfg.newmix", root=tmp_path, bench_dir=bdir)
 
 
 def test_refuses_to_run_without_a_tpu():

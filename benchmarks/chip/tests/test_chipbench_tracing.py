@@ -1,6 +1,6 @@
 """The trace reduction on a small made-up trace with known shares."""
 
-import chipbench_testlib as TL  # noqa: F401
+import chipbench_testlib as TL
 import pytest
 from types import SimpleNamespace
 
@@ -71,22 +71,22 @@ def test_readers_on_the_made_up_trace(name, want):
 
 
 def test_decode_readers_from_recorded_work():
-    m = {"hidden_size": 8, "intermediate_size": 12, "num_hidden_layers": 2,
-         "num_attention_heads": 4, "num_key_value_heads": 2, "head_dim": 2,
-         "vocab_size": 10}
+    fam = TL.DENSE
+    m = fam.entry_for(TL.dense_cfg(2, d_model=8, n_heads=4, n_kv_heads=2,
+                                   head_dim=2, d_ff=12, vocab_size=10))
     peak = {"bf16_flops": 1e3, "hbm_bytes_per_s": 1e3}
     ctx = SimpleNamespace(trace=made_up(), decode_calls=[("a", [4, 4])],
                           prefill_calls=[("a", 100), ("a", 300)],
-                          models_by_arch={"a": m}, block_tokens=4, chips=2,
-                          peak=peak)
-    from harness import flops as F
-    fl = F.decode_step_flops(m, [4, 4])
+                          models_by_arch={"a": m},
+                          family_of={"a": fam}.__getitem__, block_tokens=4,
+                          chips=2, peak=peak)
+    fl = fam.decode_step_flops(m, [4, 4])
     assert spec.load_reader("step.decode_mfu").read(ctx) == \
         pytest.approx(100 * fl * 1 / (5.5 * 2 * 1e3))
     # 1.5 s of prefill for 1 call of 200 tokens on average
     assert spec.load_reader("step.prefill_ms_per_ktok").read(ctx) == \
         pytest.approx(1e3 * 1.5 / 0.2)
-    afl, aby = F.paged_attention_cost(m, [4, 4], 4)
+    afl, aby = fam.paged_attention_cost(m, [4, 4], 4)
     least = max(afl / 1e3, aby / 1e3)
     assert spec.load_reader("kernel.paged_attn_roofline").read(ctx) == \
         pytest.approx(100 * least * 1 / 1.0)
